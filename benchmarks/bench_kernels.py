@@ -15,9 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops, ref
+from repro.utils.device_peaks import TARGET_KIND, peaks
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+# the roofline columns model one TPU v5e chip, whatever this host runs on
+PEAK_FLOPS = peaks(TARGET_KIND)["flops_bf16"]
+HBM_BW = peaks(TARGET_KIND)["hbm_bytes_per_s"]
 
 
 def _time(fn, *args, reps=3):

@@ -21,8 +21,10 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # B/s
+from repro.utils.device_peaks import TARGET_KIND, peaks
+
+PEAK_FLOPS = peaks(TARGET_KIND)["flops_bf16"]       # per chip
+HBM_BW = peaks(TARGET_KIND)["hbm_bytes_per_s"]
 LINK_BW = 50e9               # B/s per ICI link
 
 COLL_FACTORS = {"all-reduce": 2.0, "all-gather": 1.0, "reduce-scatter": 1.0,

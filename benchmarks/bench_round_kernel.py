@@ -34,11 +34,13 @@ import sys
 import time
 from pathlib import Path
 
+from repro.utils.device_peaks import TARGET_KIND, peaks
+
 ROOT = Path(__file__).resolve().parents[1]
 
-# TPU v5e numbers, matching benchmarks/bench_roofline.py
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+# the roofline row models one TPU v5e chip, whatever this host runs on
+PEAK_FLOPS = peaks(TARGET_KIND)["flops_bf16"]
+HBM_BW = peaks(TARGET_KIND)["hbm_bytes_per_s"]
 
 
 def _round_inputs(k: int, n_req: int, rounds: int, seed: int = 0):
@@ -216,23 +218,24 @@ def bench_sweep_end_to_end(k: int, rounds: int) -> dict:
             "speedup": round(t_base / max(t_fused, 1e-9), 3)}
 
 
-def roofline_row(k: int, s_round: int = 5, window: int = 5) -> dict:
-    """Roofline terms for ONE fused round on TPU v5e: the kernel streams
-    every [K] state array in and out once (the HBM floor), and computes
-    O(S·K) VPU flops for the S argmax sweeps — decisively memory-bound.
+def roofline_row(k: int, s_round: int = 5,
+                 policy: str = "elementwise_ucb") -> dict:
+    """Roofline terms for ONE fused round on TPU v5e: the whole-state
+    selection kernel reads the policy's statistic columns and the
+    candidate mask once (the HBM floor; its output and the O(S) tail are
+    negligible), and computes O(S·K) VPU flops for the S argmax sweeps —
+    memory-bound.  Byte model matches kernels/bandit_round.select_pallas:
+    ``POLICY_STATS[policy]`` columns plus the mask, 4 B per arm each."""
+    from repro.core import bandit_jax
 
-    Byte model matches kernels/bandit_round.py's actual refs: 10 per-arm
-    state vectors + mask/t_ud/t_ul/rand in, 10 state vectors out, the two
-    [K, W] ring buffers both ways (scalars are negligible)."""
     f32 = 4
-    state_bytes = ((10 + 4) * k + 2 * k * window) * f32
-    out_bytes = (10 * k + 2 * k * window) * f32
-    flops = s_round * k * 10 + k * 12
-    t_mem = (state_bytes + out_bytes) / HBM_BW
+    in_bytes = (len(bandit_jax.POLICY_STATS[policy]) + 1) * k * f32
+    flops = s_round * k * 12 + k * 12
+    t_mem = in_bytes / HBM_BW
     t_compute = flops / PEAK_FLOPS
     return {
-        "k": k, "bytes_accessed": state_bytes + out_bytes, "flops": flops,
-        "t_memory_s": t_mem, "t_compute_s": t_compute,
+        "k": k, "policy": policy, "bytes_accessed": in_bytes,
+        "flops": flops, "t_memory_s": t_mem, "t_compute_s": t_compute,
         "dominant": "memory" if t_mem >= t_compute else "compute",
         "roofline_rounds_per_s": round(1.0 / max(t_mem, t_compute), 1),
     }
@@ -282,8 +285,8 @@ def main(fast: bool = False) -> list[str]:
     results["roofline"] = roofline_row(ks[-1])
     r = results["roofline"]
     out.append(f"round_kernel/roofline_K{r['k']},,"
-               f"mem={r['t_memory_s']*1e6:.1f}us "
-               f"compute={r['t_compute_s']*1e6:.1f}us dom={r['dominant']} "
+               f"mem={r['t_memory_s']*1e6:.3f}us "
+               f"compute={r['t_compute_s']*1e6:.3f}us dom={r['dominant']} "
                f"bound={r['roofline_rounds_per_s']:.0f}r/s (TPU v5e model)")
 
     results["parity_failures"] = failures

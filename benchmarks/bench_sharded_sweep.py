@@ -1,8 +1,12 @@
 """Sharded sweep benchmark: the client-sharded segmented path at scale.
 
-Three jobs in one subprocess (XLA_FLAGS force 8 virtual host devices, the
-same mechanism the CI sharded-equivalence job uses — the parent process has
-already initialized jax, so the measurement must re-init in a child):
+Three jobs, run over the real devices in-process on an accelerator, and
+on CPU hosts (``JAX_PLATFORMS=cpu``) in a subprocess whose XLA_FLAGS force
+8 virtual host devices — the same mechanism the CI sharded-equivalence job
+uses; the parent may already have initialized jax, so the virtual devices
+need a fresh process.  The choice is made from the environment alone,
+before the parent touches jax: a child must never be started where the
+parent may hold the chip.
 
   * **Headroom curve**: the client-sharded segmented sweep
     (``sweep(shard="clients")`` routed through
@@ -45,9 +49,6 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[1]
 
 CHILD = r"""
-import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
 import dataclasses
 import json
 import time
@@ -55,7 +56,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import bandit_jax
@@ -141,7 +142,7 @@ def round_parity(k, n_rounds=3, s_round=5):
         seg_run = jax.jit(shard_map(
             seg_body, mesh=mesh,
             in_specs=(P("clients"), P("clients"), P("clients")),
-            out_specs=(state_specs, P()), check_rep=False))
+            out_specs=(state_specs, P()), check_vma=False))
 
         sf, sel_f = jax.tree.map(np.asarray, flat_run(theta, gamma, n_s))
         ss, sel_s = jax.tree.map(np.asarray, seg_run(theta, gamma, n_s))
@@ -224,7 +225,7 @@ if not fast:
             f"hierarchy: cells {times['cells']:.2f}s not faster than flat "
             f"{times['flat']:.2f}s at K=1e5 under metro-congestion")
 
-print("RESULT " + json.dumps({
+RESULT = {
     "devices": N_DEV,
     "grid": len(kw["policies"]) * len(etas) * seeds,
     "rounds": rounds,
@@ -234,16 +235,24 @@ print("RESULT " + json.dumps({
     "memory_by_k": mem_by_k,
     "hierarchy": hier,
     "failures": failures,
-}))
+}
+print("RESULT " + json.dumps(RESULT))
 """
 
 
-def _run_child(fast: bool) -> dict:
+def _run(fast: bool) -> dict:
+    """The three jobs in a forced-8-device child on CPU hosts, else
+    in-process over the real devices."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        ns: dict = {}
+        exec(compile(CHILD.replace("__FAST__", repr(fast)), "bench_sharded",
+                     "exec"), ns)
+        return ns["RESULT"]
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [str(ROOT / "src")] + ([env["PYTHONPATH"]]
                                if env.get("PYTHONPATH") else []))
-    env.pop("XLA_FLAGS", None)          # the child sets its own
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     proc = subprocess.run(
         [sys.executable, "-c", CHILD.replace("__FAST__", repr(fast))],
         env=env, capture_output=True, text=True, timeout=7200)
@@ -254,7 +263,7 @@ def _run_child(fast: bool) -> dict:
 
 
 def main(fast: bool = False) -> list[str]:
-    r = _run_child(fast)
+    r = _run(fast)
     rounds_total = r["grid"] * r["rounds"]
     speedup = r["single_s"] / max(r["sharded_s"], 1e-9)
     out = ["name,us_per_call,derived"]

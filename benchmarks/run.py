@@ -23,7 +23,8 @@ benches.  Prints ``name,us_per_call,derived`` CSV lines.
             (subprocess with 8 forced host devices; BENCH_sharded_sweep.json)
 
 ``python -m benchmarks.run --fast`` runs reduced sizes (CI); default runs
-the full paper-scale settings.
+the full paper-scale settings.  Every section runs and prints its lines;
+the run then exits non-zero if any section failed.
 """
 
 from __future__ import annotations
@@ -34,15 +35,18 @@ import time
 import traceback
 
 
-def _section(name: str, fn, fast: bool) -> list[str]:
+def _section(name: str, fn, fast: bool) -> tuple[list[str], bool]:
+    """Run one section; returns its lines and whether it succeeded (a
+    failure is reported as an ``_error`` line, and the run goes on so
+    every section's output is printed)."""
     t0 = time.time()
     try:
         lines = fn(fast=fast)
         lines.append(f"{name}/_wall,,{time.time()-t0:.1f}s")
-        return lines
+        return lines, True
     except Exception as e:
         traceback.print_exc()
-        return [f"{name}/_error,,{type(e).__name__}: {e}"]
+        return [f"{name}/_error,,{type(e).__name__}: {e}"], False
 
 
 def main() -> None:
@@ -53,6 +57,8 @@ def main() -> None:
                     help="comma-separated section filter")
     args = ap.parse_args()
 
+    from repro.utils.compat import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_accuracy, bench_async_serve,
                             bench_convergence, bench_drift, bench_e2e_sweep,
                             bench_fault_tolerance, bench_fl_engine,
@@ -81,9 +87,13 @@ def main() -> None:
         sections = {k: v for k, v in sections.items() if k in keep}
 
     all_lines: list[str] = []
+    failed = []
     for name, fn in sections.items():
         print(f"# --- {name} ---", file=sys.stderr)
-        all_lines += _section(name, fn, args.fast)
+        lines, ok = _section(name, fn, args.fast)
+        all_lines += lines
+        if not ok:
+            failed.append(name)
 
     seen_header = False
     for line in all_lines:
@@ -92,6 +102,8 @@ def main() -> None:
                 continue
             seen_header = True
         print(line)
+    if failed:
+        sys.exit(f"benchmark sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -45,6 +45,8 @@ def main() -> None:
             os.environ["XLA_FLAGS"] = (
                 f"{cur} --xla_force_host_platform_device_count="
                 f"{int(args.devices)}").strip()
+    from repro.utils.compat import use_compile_cache
+    use_compile_cache()
     import numpy as np                      # import after XLA_FLAGS is set
 
     from repro.fl import engine, metrics

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.utils.compat import use_compile_cache
 from repro.core import bandit_jax
 from repro.distributed import fl_parallel, sharding
 from repro.models.registry import build
@@ -22,6 +23,7 @@ from repro.optim.sgd import OptimizerConfig
 
 
 def main() -> None:
+    use_compile_cache()
     mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(4, 2),
                 ("data", "model"))
     C = 4

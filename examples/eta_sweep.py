@@ -78,6 +78,8 @@ def main() -> None:
             os.environ["XLA_FLAGS"] = (
                 f"{cur} --xla_force_host_platform_device_count="
                 f"{int(args.devices)}").strip()
+    from repro.utils.compat import use_compile_cache
+    use_compile_cache()
     from repro.sim import engine_jax        # import after XLA_FLAGS is set
 
     devices = args.devices if args.devices in (None, "all") \

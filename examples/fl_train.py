@@ -6,10 +6,12 @@ with MAB client selection, checkpoints, then simulates a crash and resumes.
 
 import tempfile
 
+from repro.utils.compat import use_compile_cache
 from repro.launch.train import main as train_main
 
 
 def main() -> None:
+    use_compile_cache()
     with tempfile.TemporaryDirectory() as ckpt:
         print("=== phase 1: train 6 rounds with checkpointing ===")
         train_main(["--arch", "cifar-cnn", "--policy", "elementwise_ucb",

@@ -13,6 +13,7 @@ from repro.fl.lm_trainer import LmFlTrainer
 from repro.fl.server import FederatedServer, FLConfig
 from repro.sim.network import make_network_env
 from repro.sim.resources import ResourceModel
+from repro.utils.compat import use_compile_cache
 
 
 def main() -> None:
@@ -20,6 +21,7 @@ def main() -> None:
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--rounds", type=int, default=6)
     args = ap.parse_args()
+    use_compile_cache()
 
     n_clients = 10
     rng = np.random.default_rng(0)

@@ -8,6 +8,7 @@ prints the elapsed-time comparison — the paper's headline result.
 
 import numpy as np
 
+from repro.utils.compat import use_compile_cache
 from repro.core.bandit import make_policy
 from repro.fl.server import FederatedServer, FLConfig
 from repro.sim.network import make_network_env
@@ -26,6 +27,7 @@ def run(policy: str) -> float:
 
 
 def main() -> None:
+    use_compile_cache()
     print(f"K=100 clients, C=0.1, S_round=5, eta={ETA}, {ROUNDS} rounds\n")
     fed = run("fedcs")
     for policy in ["fedcs", "extended_fedcs", "naive_ucb",

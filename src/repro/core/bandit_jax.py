@@ -232,18 +232,53 @@ def observe(state: BanditState, idx: jnp.ndarray, t_ud: jnp.ndarray,
     )
 
 
+def flat_index(shape: tuple[int, ...]) -> jnp.ndarray:
+    """Row-major flat position of every element of a 1-D or 2-D array.
+
+    The selection primitives below name an element by this position and
+    read it back by a masked reduction instead of a gather: Mosaic (the
+    Pallas TPU compiler) lowers neither a traced gather nor a scatter on a
+    loaded vector, and the kernels hold their [K] state as [K/128, 128]
+    tiles, so the same code serves the 1-D jnp paths and the 2-D kernels.
+    """
+    pos = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    if len(shape) == 2:
+        pos = pos + jax.lax.broadcasted_iota(jnp.int32, shape, 0) * shape[1]
+    return pos
+
+
+def _first_max(score: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
+    """Flat position of the first maximum of ``score`` — ``jnp.argmax``'s
+    answer (lowest index on ties) for NaN-free input."""
+    m = jnp.max(score)
+    return jnp.min(jnp.where(score == m, pos, jnp.iinfo(jnp.int32).max))
+
+
+def _at(x: jnp.ndarray, hit: jnp.ndarray) -> jnp.ndarray:
+    """``x`` at the one element where ``hit`` holds, as a reduction: every
+    other lane is the identity of ``max``, so the value is exact (its sign
+    of zero included)."""
+    fill = (-jnp.inf if jnp.issubdtype(x.dtype, jnp.floating)
+            else jnp.iinfo(x.dtype).min)
+    return jnp.max(jnp.where(hit, x, fill))
+
+
 def greedy_slots(est_ud: jnp.ndarray, est_ul: jnp.ndarray,
                  valid: jnp.ndarray, s_round: int) -> jnp.ndarray:
     """Algorithm 1 on per-arm estimates of ANY shape — the full [K] state
-    (``valid`` = candidate mask; returns client indices) or a candidate-
-    compacted [C] slice (``valid`` = in-range mask; returns slot indices).
+    (``valid`` = candidate mask; returns client indices), a candidate-
+    compacted [C] slice (``valid`` = in-range mask; returns slot indices)
+    or the [K/128, 128] tiles of the Pallas kernel (returns flat indices).
     Returns [s_round] picks, -1 padded.
 
     The ONE copy of the per-step Algorithm-1 arithmetic, shared by the
     mask-based fallback (``_greedy_tinc``), the compacted reference
     (kernels/ref.py) and the Pallas kernel body (kernels/bandit_round.py)
     — any tie-break or clamp change lands in all three at once, which the
-    bitwise-parity tests require.
+    bitwise-parity tests require.  Reads and writes at the picked arm are
+    masked reductions and selects (:func:`flat_index`), so the body
+    lowers in Mosaic; the mask rides the loop as int32, the form Mosaic
+    carries through a loop.
 
     Ties break toward the lowest index (argmax convention), matching the
     numpy reference when candidates are fed in sorted order.  As in the
@@ -251,44 +286,67 @@ def greedy_slots(est_ud: jnp.ndarray, est_ul: jnp.ndarray,
     exploration sentinel cannot poison later T_inc comparisons (in float32
     a t of -BIG would absorb every real time difference entirely).
     """
+    pos = flat_index(est_ud.shape)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (s_round,), 0)
+
     def body(i, carry):
         sel, mask, t, t_d = carry
         new_t_d = jnp.maximum(t_d, est_ul)
         tinc = (new_t_d - t_d) + jnp.maximum(est_ud - (t - t_d), 0.0) + est_ul
-        score = jnp.where(mask, -tinc, -jnp.inf)
-        x = jnp.argmax(score)
-        ok = mask[x]
-        sel = sel.at[i].set(jnp.where(ok, x, -1))
-        mask = mask.at[x].set(False)
-        t = jnp.where(ok, jnp.maximum(t + tinc[x], 0.0), t)
-        t_d = jnp.where(ok, jnp.maximum(t_d, est_ul[x]), t_d)
+        score = jnp.where(mask != 0, -tinc, -jnp.inf)
+        x = _first_max(score, pos)
+        hit = pos == x
+        ok = _at(mask, hit) != 0
+        sel = jnp.where(slot == i, jnp.where(ok, x, -1), sel)
+        mask = jnp.where(hit, 0, mask)
+        t = jnp.where(ok, jnp.maximum(t + _at(tinc, hit), 0.0), t)
+        t_d = jnp.where(ok, jnp.maximum(t_d, _at(est_ul, hit)), t_d)
         return sel, mask, t, t_d
 
     sel0 = jnp.full((s_round,), -1, jnp.int32)
     sel, *_ = jax.lax.fori_loop(
-        0, s_round, body, (sel0, valid, jnp.float32(0), jnp.float32(0)))
+        0, s_round, body,
+        (sel0, valid.astype(jnp.int32), jnp.float32(0), jnp.float32(0)))
     return sel
+
+
+def top_slots_values(score: jnp.ndarray, valid: jnp.ndarray,
+                     s_round: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Sort-free top-S over a score array of any shape (1-D, or the 2-D
+    tiles of a Pallas kernel): S iterations of masked argmax.  Returns
+    ``(sel, vals)``, both [s_round]: the picked flat indices (-1 padded)
+    and their scores (-inf padded).  Equal scores resolve to the lowest
+    index first — exactly ``lax.top_k``'s stable tie order, so it selects
+    bitwise-identically to ``_top_score`` (which the fallback keeps for
+    its single-dispatch top_k).  Shared by the compacted reference and the
+    Pallas kernel bodies; lowers in Mosaic like :func:`greedy_slots`."""
+    pos = flat_index(score.shape)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (s_round,), 0)
+
+    def body(i, carry):
+        sel, vals, mask = carry
+        s = jnp.where(mask != 0, score, -jnp.inf)
+        x = _first_max(s, pos)
+        hit = pos == x
+        ok = _at(mask, hit) != 0
+        sel = jnp.where(slot == i, jnp.where(ok, x, -1), sel)
+        vals = jnp.where(slot == i, jnp.where(ok, _at(s, hit), -jnp.inf),
+                         vals)
+        return sel, vals, jnp.where(hit, 0, mask)
+
+    sel, vals, _ = jax.lax.fori_loop(
+        0, s_round, body,
+        (jnp.full((s_round,), -1, jnp.int32),
+         jnp.full((s_round,), -jnp.inf, jnp.float32),
+         valid.astype(jnp.int32)))
+    return sel, vals
 
 
 def top_slots(score: jnp.ndarray, valid: jnp.ndarray,
               s_round: int) -> jnp.ndarray:
-    """Sort-free top-S over a score array of any shape: S iterations of
-    masked argmax, -1 padded.  Equal scores resolve to the lowest index
-    first — exactly ``lax.top_k``'s stable tie order, so it selects
-    bitwise-identically to ``_top_score`` (which the fallback keeps for
-    its single-dispatch top_k).  Shared by the compacted reference and the
-    Pallas kernel body."""
-    def body(i, carry):
-        sel, mask = carry
-        s = jnp.where(mask, score, -jnp.inf)
-        x = jnp.argmax(s)
-        ok = mask[x]
-        sel = sel.at[i].set(jnp.where(ok, x, -1))
-        return sel, mask.at[x].set(False)
-
-    sel0 = jnp.full((s_round,), -1, jnp.int32)
-    sel, _ = jax.lax.fori_loop(0, s_round, body, (sel0, valid))
-    return sel
+    """:func:`top_slots_values` without the values: [s_round] picks, -1
+    padded."""
+    return top_slots_values(score, valid, s_round)[0]
 
 
 def _greedy_tinc(est_ud: jnp.ndarray, est_ul: jnp.ndarray,
@@ -894,9 +952,9 @@ def make_sampled_round_fn(policy: str, s_round: int, *,
     this round's time-draw PRNG key.  The round never materializes [K]
     resource draws: it draws ONE [2, C] uniform block from ``k_time``
     (bitwise the stream of sim.engine_jax.sample_times_candidates with the
-    same key) and the transform to (t_UD, t_UL) runs inside the fused
-    round — in-VMEM in the Pallas kernel on TPU, on the [C] slice in the
-    jnp reference elsewhere (kernels/ops.bandit_round_sampled routes).
+    same key) and the transform to (t_UD, t_UL) runs on that [C] slice
+    inside the fused round (kernels/ops.bandit_round_sampled routes the
+    selection to the Pallas kernel on TPU, the jnp reference elsewhere).
 
     The random policy still draws its [K] uniform stream from ``key`` so
     the fast path's fused and unfused executions stay bitwise-identical,

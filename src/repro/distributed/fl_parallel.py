@@ -26,7 +26,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.distributed import compression
 from repro.distributed.sharding import cohort_axes
@@ -151,7 +151,7 @@ def fedavg_across_cohorts(stacked_params: Any, weights: jnp.ndarray,
             block, mesh=mesh,
             in_specs=(in_spec, P()),
             out_specs=out_spec,
-            check_rep=False,
+            check_vma=False,
         )(delta, w)
         return res[0]          # drop the collapsed cohort dim
 

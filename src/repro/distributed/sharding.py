@@ -54,7 +54,7 @@ from typing import TYPE_CHECKING, Any, Callable, Sequence
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import batch_axes
@@ -122,7 +122,7 @@ def shard_vmapped(vm_fn: Callable, mesh: Mesh,
         in_specs = tuple(P(axis_name) if i in sharded else P()
                          for i in range(len(args)))
         return shard_map(vm_fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(axis_name), check_rep=False)(*args)
+                         out_specs=P(axis_name), check_vma=False)(*args)
     return wrapper
 
 

@@ -1,32 +1,33 @@
-"""Pallas TPU kernel: one fused bandit round over the K-sized BanditState.
+"""Pallas TPU kernels: whole-state bandit selection and the per-shard top-S.
 
-The per-round hot path of every sweep — policy scoring, candidate-masked
-Algorithm-1 / top-S selection, the realized upload schedule, and the
-``observe`` statistics update — currently dispatches ~a dozen small K-sized
-XLA ops per round, each round-tripping the [K] state arrays through HBM.
-This kernel performs the whole round in a single ``pallas_call``: every
-state array streams HBM -> VMEM once, the S-step selection loop and the
-schedule run entirely on VMEM-resident values, and the updated state
-streams back out — the roofline minimum of ~2 passes over the state.
-
-Scoring arithmetic is ``core.bandit_jax.policy_scores`` (the single shared
-definition, pure jnp, legal inside a kernel body) and the state update
-mirrors ``core.bandit_jax.observe`` expression-for-expression, so kernel
-outputs are bitwise-identical to the compacted jnp reference
-(``kernels/ref.py::bandit_round_ref``) — the CI bench-smoke gate
+The O(K) part of every round — policy scoring over the [K] state and the
+S-pass candidate-masked Algorithm-1 / top-S selection — runs here as one
+``pallas_call``: the policy's statistic columns stream HBM -> VMEM once
+and the S selection passes run on VMEM-resident values.  What follows the
+selection touches only the S picked clients (gather of their times, the
+realized schedule, the failure layer, the ``observe`` scatter), so it runs
+as the same XLA code as the compacted reference
+(``kernels/ref.py::finish_round``) — outputs are bitwise-identical to
+``ref.bandit_round_ref``, and the CI bench-smoke gate
 (benchmarks/bench_round_kernel.py) fails on any divergence.
 
-Selection is *sort-free*: S iterations of masked argmax (lowest index wins
-ties, the numpy reference's convention), not a top-k sort.
+Scoring arithmetic is ``core.bandit_jax.policy_scores`` and selection is
+``core.bandit_jax.greedy_slots`` / ``top_slots_values`` — the single shared
+definitions, written so Mosaic lowers them: a read at a traced position is
+a masked reduction over a flat iota, a write is a select.  Selection is
+*sort-free*: S iterations of masked argmax (lowest index wins ties, the
+numpy reference's convention).
 
-Layout notes: all per-arm arrays are 1-D [K] padded to a multiple of
-``BLOCK`` (padded arms are never candidates, so they are inert); the ring
-buffers ride along as [K, W].  The kernel keeps the whole state resident
-(grid=(1,)): ~16 input vectors + 2 [K, W] ring buffers + ~22 output
-vectors ≈ 190 B/arm at W=5, so a 16 MB VMEM core bounds K at roughly
-8·10⁴ arms; larger K should shard clients first (``shard="clients"``).  On CPU this
-kernel exists for interpret-mode parity testing only — ops.bandit_round
-routes real CPU work to the compacted jnp reference.
+Layout: every [K] column is padded to a multiple of ``BLOCK`` and handed
+to the kernel as [K/128, 128] tiles (Mosaic's native 2-D layout; padded
+arms are never candidates, so they are inert); scalars ride as [1, 1]
+blocks, and the [S] results leave as [1, S] rows — every block is at
+least 2-D, so the kernels stay legal when ``vmap`` (the engines' grid of
+runs) adds a batch grid axis in front.  The whole selection input stays
+resident (grid=(1,) per run), so K is
+bounded by VMEM — see ``kernels/ops.KERNEL_MAX_K`` for the bound and how
+it was reckoned.  On CPU these kernels exist for interpret-mode parity
+testing only — kernels/ops routes real CPU work to the jnp reference.
 """
 
 from __future__ import annotations
@@ -36,251 +37,108 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bandit_jax
 
-BLOCK = 1024        # [K] padding granularity; multiple of 8*128
+LANES = 128
+BLOCK = 1024        # [K] padding granularity: 8 sublanes x 128 lanes
+# scoped-VMEM limit the selection kernels may use: v5e has 128 MiB of VMEM
+# per core; the default scoped limit is 16 MiB.  Raised so K up to
+# ops.KERNEL_MAX_K compiles, with headroom left below the physical size.
+VMEM_LIMIT_BYTES = 100 * 2**20
 
 
-def _round_kernel(nsel_ref, sumud_ref, sumul_ref, sumtinc_ref, lastud_ref,
-                  lastul_ref, histud_ref, histul_ref, histn_ref, discn_ref,
-                  discud_ref, discul_ref, total_ref, disctotal_ref, mask_ref,
-                  tud_ref, tul_ref, rand_ref, hyper_ref, nfail_ref, fu_ref,
-                  o_nsel, o_sumud, o_sumul, o_sumtinc, o_lastud, o_lastul,
-                  o_histud, o_histul, o_histn, o_discn, o_discud, o_discul,
-                  o_total, o_disctotal, o_sel, o_rt, o_nfail, o_flags,
-                  *, policy: str, s_round: int, w: int, decay: float,
-                  fault, deadline):
-    _round_body(
-        nsel_ref, sumud_ref, sumul_ref, sumtinc_ref, lastud_ref, lastul_ref,
-        histud_ref, histul_ref, histn_ref, discn_ref, discud_ref, discul_ref,
-        total_ref, disctotal_ref, mask_ref, tud_ref[...], tul_ref[...],
-        rand_ref, hyper_ref, nfail_ref, fu_ref, o_nsel, o_sumud, o_sumul,
-        o_sumtinc, o_lastud, o_lastul, o_histud, o_histul, o_histn, o_discn,
-        o_discud, o_discul, o_total, o_disctotal, o_sel, o_rt, o_nfail,
-        o_flags, policy=policy, s_round=s_round, w=w, decay=decay,
-        fault=fault, deadline=deadline)
+def _tiles(x, fill=0):
+    """[K] -> [Kp/128, 128], padded with ``fill`` to a BLOCK multiple."""
+    pad = (-x.shape[0]) % BLOCK
+    if pad:
+        x = jnp.pad(x, (0, pad), constant_values=fill)
+    return x.reshape(-1, LANES)
 
 
-def _sampled_round_kernel(nsel_ref, sumud_ref, sumul_ref, sumtinc_ref,
-                          lastud_ref, lastul_ref, histud_ref, histul_ref,
-                          histn_ref, discn_ref, discud_ref, discul_ref,
-                          total_ref, disctotal_ref, mask_ref, cand_ref,
-                          u2_ref, mutheta_ref, mugamma_ref, nsamp_ref,
-                          eta_ref, bits_ref, rand_ref, hyper_ref, nfail_ref,
-                          fu_ref, o_nsel, o_sumud, o_sumul, o_sumtinc,
-                          o_lastud, o_lastul, o_histud, o_histul, o_histn,
-                          o_discn, o_discud, o_discul, o_total, o_disctotal,
-                          o_sel, o_rt, o_nfail, o_flags,
-                          *, policy: str, s_round: int, w: int,
-                          decay: float, k: int, fluctuate: bool, fault,
-                          deadline):
-    """The streamed-sampling variant: the Eq. (8) truncnorm transform runs
-    HERE, in VMEM, on the [C] candidate slice (``u2_ref``: [2, C] uniforms,
-    ``mutheta_ref``/``mugamma_ref``/``nsamp_ref``: [Kp] per-client means),
-    and the resulting (t_UD, t_UL) are scattered into [Kp] buffers only
-    candidates ever read — no [K] resource draw exists anywhere.  The
-    transform is kernels/ref.truncnorm_times_ref verbatim (pure jnp), so
-    kernel and reference stay bitwise-identical."""
-    from repro.kernels.ref import truncnorm_times_ref
-
-    kp = nsel_ref.shape[0]
-    cand = cand_ref[...]
-    cvalid = cand < k
-    safe_c = jnp.where(cvalid, cand, 0)
-    t_ud_c, t_ul_c = truncnorm_times_ref(
-        u2_ref[...], mutheta_ref[...][safe_c], mugamma_ref[...][safe_c],
-        nsamp_ref[...][safe_c], eta_ref[0], bits_ref[0],
-        fluctuate=fluctuate)
-    drop_c = jnp.where(cvalid, cand, kp)
-    t_ud = jnp.zeros(kp, jnp.float32).at[drop_c].set(t_ud_c, mode="drop")
-    t_ul = jnp.zeros(kp, jnp.float32).at[drop_c].set(t_ul_c, mode="drop")
-    _round_body(
-        nsel_ref, sumud_ref, sumul_ref, sumtinc_ref, lastud_ref, lastul_ref,
-        histud_ref, histul_ref, histn_ref, discn_ref, discud_ref, discul_ref,
-        total_ref, disctotal_ref, mask_ref, t_ud, t_ul, rand_ref, hyper_ref,
-        nfail_ref, fu_ref, o_nsel, o_sumud, o_sumul, o_sumtinc, o_lastud,
-        o_lastul, o_histud, o_histul, o_histn, o_discn, o_discud, o_discul,
-        o_total, o_disctotal, o_sel, o_rt, o_nfail, o_flags, policy=policy,
-        s_round=s_round, w=w, decay=decay, fault=fault, deadline=deadline)
-
-
-def _round_body(nsel_ref, sumud_ref, sumul_ref, sumtinc_ref, lastud_ref,
-                lastul_ref, histud_ref, histul_ref, histn_ref, discn_ref,
-                discud_ref, discul_ref, total_ref, disctotal_ref, mask_ref,
-                t_ud, t_ul, rand_ref, hyper_ref, nfail_ref, fu_ref,
-                o_nsel, o_sumud, o_sumul, o_sumtinc, o_lastud, o_lastul,
-                o_histud, o_histul, o_histn, o_discn, o_discud, o_discul,
-                o_total, o_disctotal, o_sel, o_rt, o_nfail, o_flags,
-                *, policy: str, s_round: int, w: int, decay: float,
-                fault=None, deadline: float | None = None):
-    """score -> select -> schedule -> observe on VMEM-resident values;
-    ``t_ud``/``t_ul`` arrive as loaded [Kp] values (from refs in the plain
-    kernel, computed in-VMEM in the sampled one).  A static ``deadline``
-    compiles in the failure layer (core.bandit_jax.censor_slots on the
-    caller-drawn ``fu_ref`` uniforms, censored observe, n_fail counts and
-    the per-slot outcome flags); at None the body is exactly the fault-free
-    round and n_fail passes straight through."""
-    n_sel = nsel_ref[...]
-    n_fail = nfail_ref[...]
-    sum_ud, sum_ul = sumud_ref[...], sumul_ref[...]
-    sum_tinc = sumtinc_ref[...]
-    last_ud, last_ul = lastud_ref[...], lastul_ref[...]
-    hist_ud, hist_ul = histud_ref[...], histul_ref[...]
-    hist_n = histn_ref[...]
-    disc_n, disc_ud, disc_ul = discn_ref[...], discud_ref[...], discul_ref[...]
-    total, disc_total = total_ref[0], disctotal_ref[0]
-    mask = mask_ref[...] != 0
-    rand = rand_ref[...]
-    hyper = hyper_ref[0]
-    kp = n_sel.shape[0]
-
-    # ---- score (shared arithmetic with the jnp paths) --------------------
-    obs = dict(n_sel=n_sel, sum_ud=sum_ud, sum_ul=sum_ul, sum_tinc=sum_tinc,
-               last_ud=last_ud, last_ul=last_ul,
-               hist_sum_ud=hist_ud.sum(1), hist_sum_ul=hist_ul.sum(1),
-               hist_n=hist_n, disc_n=disc_n, disc_ud=disc_ud,
-               disc_ul=disc_ul)
-    kind, a, b = bandit_jax.policy_scores(policy, obs, total, disc_total,
-                                          t_ud, t_ul, rand, hyper)
-
-    # ---- sort-free masked selection (S x argmax on VMEM values): the
-    # shared core primitives, here over the full padded [Kp] arrays so the
-    # returned slots ARE client indices -------------------------------------
+def _select_kernel(*refs, policy: str, names: tuple[str, ...],
+                   s_round: int):
+    """score -> select over the resident tiles; writes [S] client
+    indices (-1 padded)."""
+    *in_refs, o_sel = refs
+    v = {n: r[...] for n, r in zip(names, in_refs)}
+    mask = v.pop("mask") != 0
+    kind, a, b = bandit_jax.policy_scores(
+        policy, v, v.pop("total"), v.pop("disc_total"), v.get("t_ud"),
+        v.get("t_ul"), v.get("rand"), v.pop("hyper"))
     if kind == "greedy":
         sel = bandit_jax.greedy_slots(a, b, mask, s_round)
     else:
         sel = bandit_jax.top_slots(a, mask, s_round)
-
-    # ---- realized schedule (same per-step math as schedule_selected) -----
-    valid = sel >= 0
-    safe = jnp.where(valid, sel, 0)
-    sud = jnp.where(valid, t_ud[safe], 0.0)
-    sul = jnp.where(valid, t_ul[safe], 0.0)
-    t_d_true = jnp.max(jnp.where(valid, sul, 0.0))
-
-    if deadline is None:
-        def tstep(i, t):
-            t2 = jnp.maximum(t, t_d_true + sud[i]) + sul[i]
-            return jnp.where(valid[i], t2, t)
-        round_time = jax.lax.fori_loop(0, s_round, tstep, t_d_true)
-        finish = None
-    else:
-        # same clock recursion, additionally recording each slot's
-        # completion offset (schedule_completions' ``finish``, bitwise)
-        def tstep(i, carry):
-            t, fin = carry
-            t2 = jnp.maximum(t, t_d_true + sud[i]) + sul[i]
-            t_new = jnp.where(valid[i], t2, t)
-            return t_new, fin.at[i].set(t_new)
-        round_time, finish = jax.lax.fori_loop(
-            0, s_round, tstep,
-            (t_d_true, jnp.zeros((s_round,), jnp.float32)))
-
-    def istep(i, carry):
-        t, td, incs = carry
-        ntd = jnp.maximum(td, sul[i])
-        inc = (ntd - td) + jnp.maximum(sud[i] - (t - td), 0.0) + sul[i]
-        incs = incs.at[i].set(jnp.where(valid[i], inc, 0.0))
-        return (jnp.where(valid[i], t + inc, t),
-                jnp.where(valid[i], ntd, td), incs)
-    _, _, incs = jax.lax.fori_loop(
-        0, s_round, istep,
-        (jnp.float32(0), jnp.float32(0), jnp.zeros((s_round,), jnp.float32)))
-
-    # ---- failure layer (shared censor_slots; compiled away at None) ------
-    if deadline is None:
-        obs_ud, obs_ul, obs_inc = sud, sul, incs
-    else:
-        obs_ud, obs_ul, obs_inc, fail, flags, round_time = \
-            bandit_jax.censor_slots(valid, sud, sul, incs, finish,
-                                    round_time, fu_ref[...], fault, deadline)
-
-    # ---- observe (expression-for-expression core.bandit_jax.observe) -----
-    drop = jnp.where(valid, safe, kp)
-    slot = n_sel[jnp.clip(sel, 0, kp - 1)] % w
-    o_nsel[...] = n_sel.at[drop].add(1, mode="drop")
-    o_sumud[...] = sum_ud.at[drop].add(obs_ud, mode="drop")
-    o_sumul[...] = sum_ul.at[drop].add(obs_ul, mode="drop")
-    o_sumtinc[...] = sum_tinc.at[drop].add(obs_inc, mode="drop")
-    o_lastud[...] = last_ud.at[drop].set(obs_ud, mode="drop")
-    o_lastul[...] = last_ul.at[drop].set(obs_ul, mode="drop")
-    o_histud[...] = hist_ud.at[drop, slot].set(obs_ud, mode="drop")
-    o_histul[...] = hist_ul.at[drop, slot].set(obs_ul, mode="drop")
-    o_histn[...] = jnp.minimum(hist_n.at[drop].add(1, mode="drop"), w)
-    o_total[0] = total + valid.sum().astype(jnp.int32)
-    if float(decay) == 1.0:     # static: stationary policies skip the decay
-        o_discn[...], o_discud[...], o_discul[...] = disc_n, disc_ud, disc_ul
-        o_disctotal[0] = disc_total
-    else:
-        o_discn[...] = (disc_n * decay).at[drop].add(1.0, mode="drop")
-        o_discud[...] = (disc_ud * decay).at[drop].add(obs_ud, mode="drop")
-        o_discul[...] = (disc_ul * decay).at[drop].add(obs_ul, mode="drop")
-        o_disctotal[0] = disc_total * decay + valid.sum(dtype=jnp.float32)
-    if deadline is None:
-        o_nfail[...] = n_fail
-        o_flags[...] = jnp.where(valid, 0, -1).astype(jnp.int32)
-    else:
-        fdrop = jnp.where(valid & fail, safe, kp)
-        o_nfail[...] = n_fail.at[fdrop].add(1, mode="drop")
-        o_flags[...] = flags
-    o_sel[...] = sel
-    o_rt[0] = round_time
+    o_sel[...] = sel.reshape(1, s_round)
 
 
-def _topk_slots_kernel(score_ref, valid_ref, o_vals, o_slots,
-                       *, s_round: int):
-    """Per-shard local top-S of the segmented multi-shard selection: S
-    iterations of masked argmax (core.bandit_jax.top_slots order — score
-    descending, lowest slot on ties) on the VMEM-resident shard-local [C]
-    score slice, emitting (value, slot) pairs for the cross-shard reduce
-    (kernels/ref.segmented_topk_ref)."""
-    score = score_ref[...]
-    valid = valid_ref[...] != 0
+def _full(shape):
+    return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
-    def body(i, carry):
-        vals, slots, mask = carry
-        s = jnp.where(mask, score, -jnp.inf)
-        x = jnp.argmax(s)
-        ok = mask[x]
-        vals = vals.at[i].set(jnp.where(ok, s[x], -jnp.inf))
-        slots = slots.at[i].set(jnp.where(ok, x.astype(jnp.int32), -1))
-        return vals, slots, mask.at[x].set(False)
 
-    vals, slots, _ = jax.lax.fori_loop(
-        0, s_round, body,
-        (jnp.full((s_round,), -jnp.inf, jnp.float32),
-         jnp.full((s_round,), -1, jnp.int32), valid))
-    o_vals[...] = vals
-    o_slots[...] = slots
+def _call(kernel, args, out_shape, name: str, interpret: bool):
+    """One grid=(1,) pallas_call with every operand whole in VMEM;
+    ``name`` is the kernel's stable name in traces."""
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid=(1,),
+        in_specs=[_full(a.shape) for a in args],
+        out_specs=tuple(_full(o.shape) for o in out_shape),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(*args)
 
 
 @functools.partial(jax.jit, static_argnames=("s_round", "interpret"))
 def topk_slots_pallas(score, valid, s_round: int, interpret: bool = True):
     """[C] scores + validity -> local top-``s_round`` (vals [S] f32,
     slots [S] int32) as one ``pallas_call``; exhausted slots are
-    (-inf, -1).  Bitwise-identical to kernels/ref.local_topk_ref.  Pads
-    [C] to a BLOCK multiple (padded lanes are never valid)."""
-    c = score.shape[0]
-    pad = (-c) % BLOCK
-    score = score.astype(jnp.float32)
-    valid = valid.astype(jnp.int32)
-    if pad:
-        score = jnp.pad(score, (0, pad), constant_values=-jnp.inf)
-        valid = jnp.pad(valid, (0, pad))
-    cp = c + pad
+    (-inf, -1).  Bitwise-identical to kernels/ref.local_topk_ref."""
+    def kernel(score_ref, valid_ref, o_vals, o_slots):
+        sel, vals = bandit_jax.top_slots_values(
+            score_ref[...], valid_ref[...] != 0, s_round)
+        o_vals[...] = vals.reshape(1, s_round)
+        o_slots[...] = sel.reshape(1, s_round)
 
-    spec1 = pl.BlockSpec((cp,), lambda i: (0,))
-    spec_s = pl.BlockSpec((s_round,), lambda i: (0,))
-    return pl.pallas_call(
-        functools.partial(_topk_slots_kernel, s_round=s_round),
-        grid=(1,),
-        in_specs=[spec1, spec1],
-        out_specs=(spec_s, spec_s),
-        out_shape=(jax.ShapeDtypeStruct((s_round,), jnp.float32),
-                   jax.ShapeDtypeStruct((s_round,), jnp.int32)),
-        interpret=interpret,
-    )(score, valid)
+    vals, slots = _call(kernel,
+                        [_tiles(score.astype(jnp.float32), -jnp.inf),
+                         _tiles(valid.astype(jnp.int32))],
+                        (jax.ShapeDtypeStruct((1, s_round), jnp.float32),
+                         jax.ShapeDtypeStruct((1, s_round), jnp.int32)),
+                        "topk_slots", interpret)
+    return vals[0], slots[0]
+
+
+def select_pallas(state, mask, t_ud, t_ul, rand, hyper, *, policy: str,
+                  s_round: int, interpret: bool):
+    """Whole-state selection: [K] candidate ``mask`` -> [S] client indices
+    (-1 padded), bitwise the mask-based select fns.  Only the columns the
+    policy reads (``core.bandit_jax.POLICY_STATS``) enter the kernel; the
+    ring-buffer sums are reduced over the window in XLA first."""
+    obs = bandit_jax.state_obs(state)
+    cols = {n: obs[n] for n in bandit_jax.POLICY_STATS[policy]}
+    if policy == "oracle":
+        cols.update(t_ud=t_ud, t_ul=t_ul)
+    if policy == "random":
+        cols["rand"] = rand
+    cols = {n: _tiles(x) for n, x in cols.items()}
+    cols.update(
+        mask=_tiles(mask.astype(jnp.int32)),
+        total=state.total.reshape(1, 1),
+        disc_total=state.disc_total.reshape(1, 1),
+        hyper=jnp.asarray(hyper, jnp.float32).reshape(1, 1))
+    names = tuple(cols)
+    return _call(
+        functools.partial(_select_kernel, policy=policy, names=names,
+                          s_round=s_round),
+        [cols[n] for n in names],
+        (jax.ShapeDtypeStruct((1, s_round), jnp.int32),),
+        f"select_{policy}", interpret)[0][0]
 
 
 @functools.partial(jax.jit, static_argnames=("policy", "s_round", "decay",
@@ -293,173 +151,12 @@ def bandit_round_pallas(state, cand_idx, t_ud, t_ul, rand, hyper, *,
     """Fused round on a BanditState; same contract as ref.bandit_round_ref
     (``cand_idx``: [C] sorted, >= K padding).  Returns (state, sel, rt) —
     plus the per-slot flags with the failure layer on (``deadline`` set)."""
+    from repro.kernels.ref import finish_round
+
     k = t_ud.shape[0]
-    w = state.hist_ud.shape[1]
-    pad = (-k) % BLOCK
-    kp = k + pad
-
-    def pad1(x):
-        return jnp.pad(x, (0, pad)) if pad else x
-
-    # candidate mask at padded length; >= K entries (and the padded arms
-    # themselves) stay out of the candidate set
-    mask = jnp.zeros(kp, jnp.int32).at[
-        jnp.where(cand_idx < k, cand_idx, kp)].set(1, mode="drop")
-    rand = jnp.zeros(k, jnp.float32) if rand is None else rand
-    fu = (jnp.zeros((3, s_round), jnp.float32) if fault_u is None
-          else fault_u)
-
-    spec1 = pl.BlockSpec((kp,), lambda i: (0,))
-    spec2 = pl.BlockSpec((kp, w), lambda i: (0, 0))
-    spec_s = pl.BlockSpec((1,), lambda i: (0,))
-    spec_sel = pl.BlockSpec((s_round,), lambda i: (0,))
-    spec_fu = pl.BlockSpec((3, s_round), lambda i: (0, 0))
-
-    out_shape = (
-        jax.ShapeDtypeStruct((kp,), jnp.int32),       # n_sel
-        *(jax.ShapeDtypeStruct((kp,), jnp.float32) for _ in range(5)),
-        jax.ShapeDtypeStruct((kp, w), jnp.float32),   # hist_ud
-        jax.ShapeDtypeStruct((kp, w), jnp.float32),   # hist_ul
-        jax.ShapeDtypeStruct((kp,), jnp.int32),       # hist_n
-        *(jax.ShapeDtypeStruct((kp,), jnp.float32) for _ in range(3)),
-        jax.ShapeDtypeStruct((1,), jnp.int32),        # total
-        jax.ShapeDtypeStruct((1,), jnp.float32),      # disc_total
-        jax.ShapeDtypeStruct((s_round,), jnp.int32),  # sel
-        jax.ShapeDtypeStruct((1,), jnp.float32),      # round_time
-        jax.ShapeDtypeStruct((kp,), jnp.int32),       # n_fail
-        jax.ShapeDtypeStruct((s_round,), jnp.int32),  # flags
-    )
-    out_specs = (spec1, spec1, spec1, spec1, spec1, spec1, spec2, spec2,
-                 spec1, spec1, spec1, spec1, spec_s, spec_s, spec_sel,
-                 spec_s, spec1, spec_sel)
-    in_specs = [spec1] * 6 + [spec2, spec2] + [spec1] * 4 + \
-        [spec_s, spec_s] + [spec1] * 4 + [spec_s] + [spec1, spec_fu]
-
-    outs = pl.pallas_call(
-        functools.partial(_round_kernel, policy=policy, s_round=s_round,
-                          w=w, decay=float(decay), fault=fault,
-                          deadline=deadline),
-        grid=(1,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(pad1(state.n_sel), pad1(state.sum_ud), pad1(state.sum_ul),
-      pad1(state.sum_tinc), pad1(state.last_ud), pad1(state.last_ul),
-      jnp.pad(state.hist_ud, ((0, pad), (0, 0))) if pad else state.hist_ud,
-      jnp.pad(state.hist_ul, ((0, pad), (0, 0))) if pad else state.hist_ul,
-      pad1(state.hist_n), pad1(state.disc_n), pad1(state.disc_ud),
-      pad1(state.disc_ul), state.total.reshape(1),
-      state.disc_total.reshape(1), mask,
-      pad1(t_ud.astype(jnp.float32)), pad1(t_ul.astype(jnp.float32)),
-      pad1(rand.astype(jnp.float32)),
-      jnp.asarray(hyper, jnp.float32).reshape(1),
-      pad1(state.n_fail), fu.astype(jnp.float32))
-
-    new_state = state.replace(
-        n_sel=outs[0][:k], sum_ud=outs[1][:k], sum_ul=outs[2][:k],
-        sum_tinc=outs[3][:k], last_ud=outs[4][:k], last_ul=outs[5][:k],
-        hist_ud=outs[6][:k], hist_ul=outs[7][:k], hist_n=outs[8][:k],
-        disc_n=outs[9][:k], disc_ud=outs[10][:k], disc_ul=outs[11][:k],
-        total=outs[12][0], disc_total=outs[13][0], n_fail=outs[16][:k])
-    if deadline is None:
-        return new_state, outs[14], outs[15][0]
-    return new_state, outs[14], outs[15][0], outs[17]
-
-
-@functools.partial(jax.jit, static_argnames=("policy", "s_round", "decay",
-                                             "fluctuate", "interpret",
-                                             "fault", "deadline"))
-def bandit_round_pallas_sampled(state, cand_idx, u2, rand, theta_mu,
-                                gamma_mu, n_samples, eta, model_bits, hyper,
-                                *, policy: str, s_round: int,
-                                decay: float = 1.0, fluctuate: bool = True,
-                                interpret: bool = True,
-                                fault: tuple | None = None,
-                                deadline: float | None = None,
-                                fault_u=None):
-    """Fused round that draws its own Eq. (8) times in-VMEM; same contract
-    as ops.bandit_round_sampled (``cand_idx``: [C] sorted, >= K padding;
-    ``u2``: [2, C] uniforms or None; ``theta_mu``/``gamma_mu``/
-    ``n_samples``: [K] means).  Returns (state, sel, rt) — plus the
-    per-slot flags with the failure layer on (``deadline`` set)."""
-    k = theta_mu.shape[0]
-    w = state.hist_ud.shape[1]
-    c = cand_idx.shape[0]
-    pad = (-k) % BLOCK
-    kp = k + pad
-
-    def pad1(x):
-        return jnp.pad(x, (0, pad)) if pad else x
-
-    mask = jnp.zeros(kp, jnp.int32).at[
-        jnp.where(cand_idx < k, cand_idx, kp)].set(1, mode="drop")
-    u2 = jnp.zeros((2, c), jnp.float32) if u2 is None else u2
-    rand = jnp.zeros(k, jnp.float32) if rand is None else rand
-    fu = (jnp.zeros((3, s_round), jnp.float32) if fault_u is None
-          else fault_u)
-
-    spec1 = pl.BlockSpec((kp,), lambda i: (0,))
-    spec2 = pl.BlockSpec((kp, w), lambda i: (0, 0))
-    spec_s = pl.BlockSpec((1,), lambda i: (0,))
-    spec_c = pl.BlockSpec((c,), lambda i: (0,))
-    spec_u2 = pl.BlockSpec((2, c), lambda i: (0, 0))
-    spec_sel = pl.BlockSpec((s_round,), lambda i: (0,))
-    spec_fu = pl.BlockSpec((3, s_round), lambda i: (0, 0))
-
-    out_shape = (
-        jax.ShapeDtypeStruct((kp,), jnp.int32),       # n_sel
-        *(jax.ShapeDtypeStruct((kp,), jnp.float32) for _ in range(5)),
-        jax.ShapeDtypeStruct((kp, w), jnp.float32),   # hist_ud
-        jax.ShapeDtypeStruct((kp, w), jnp.float32),   # hist_ul
-        jax.ShapeDtypeStruct((kp,), jnp.int32),       # hist_n
-        *(jax.ShapeDtypeStruct((kp,), jnp.float32) for _ in range(3)),
-        jax.ShapeDtypeStruct((1,), jnp.int32),        # total
-        jax.ShapeDtypeStruct((1,), jnp.float32),      # disc_total
-        jax.ShapeDtypeStruct((s_round,), jnp.int32),  # sel
-        jax.ShapeDtypeStruct((1,), jnp.float32),      # round_time
-        jax.ShapeDtypeStruct((kp,), jnp.int32),       # n_fail
-        jax.ShapeDtypeStruct((s_round,), jnp.int32),  # flags
-    )
-    out_specs = (spec1, spec1, spec1, spec1, spec1, spec1, spec2, spec2,
-                 spec1, spec1, spec1, spec1, spec_s, spec_s, spec_sel,
-                 spec_s, spec1, spec_sel)
-    in_specs = [spec1] * 6 + [spec2, spec2] + [spec1] * 4 + \
-        [spec_s, spec_s] + [spec1, spec_c, spec_u2] + [spec1] * 3 + \
-        [spec_s, spec_s] + [spec1, spec_s] + [spec1, spec_fu]
-
-    outs = pl.pallas_call(
-        functools.partial(_sampled_round_kernel, policy=policy,
-                          s_round=s_round, w=w, decay=float(decay), k=k,
-                          fluctuate=bool(fluctuate), fault=fault,
-                          deadline=deadline),
-        grid=(1,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(pad1(state.n_sel), pad1(state.sum_ud), pad1(state.sum_ul),
-      pad1(state.sum_tinc), pad1(state.last_ud), pad1(state.last_ul),
-      jnp.pad(state.hist_ud, ((0, pad), (0, 0))) if pad else state.hist_ud,
-      jnp.pad(state.hist_ul, ((0, pad), (0, 0))) if pad else state.hist_ul,
-      pad1(state.hist_n), pad1(state.disc_n), pad1(state.disc_ud),
-      pad1(state.disc_ul), state.total.reshape(1),
-      state.disc_total.reshape(1), mask, cand_idx.astype(jnp.int32),
-      u2.astype(jnp.float32), pad1(theta_mu.astype(jnp.float32)),
-      pad1(gamma_mu.astype(jnp.float32)),
-      pad1(n_samples.astype(jnp.float32)),
-      jnp.asarray(eta, jnp.float32).reshape(1),
-      jnp.asarray(model_bits, jnp.float32).reshape(1),
-      pad1(rand.astype(jnp.float32)),
-      jnp.asarray(hyper, jnp.float32).reshape(1),
-      pad1(state.n_fail), fu.astype(jnp.float32))
-
-    new_state = state.replace(
-        n_sel=outs[0][:k], sum_ud=outs[1][:k], sum_ul=outs[2][:k],
-        sum_tinc=outs[3][:k], last_ud=outs[4][:k], last_ul=outs[5][:k],
-        hist_ud=outs[6][:k], hist_ul=outs[7][:k], hist_n=outs[8][:k],
-        disc_n=outs[9][:k], disc_ud=outs[10][:k], disc_ul=outs[11][:k],
-        total=outs[12][0], disc_total=outs[13][0], n_fail=outs[16][:k])
-    if deadline is None:
-        return new_state, outs[14], outs[15][0]
-    return new_state, outs[14], outs[15][0], outs[17]
+    mask = jnp.zeros(k, bool).at[cand_idx].set(True, mode="drop")
+    sel = select_pallas(state, mask, t_ud, t_ul, rand, hyper, policy=policy,
+                        s_round=s_round, interpret=interpret)
+    safe = jnp.where(sel >= 0, sel, 0)
+    return finish_round(state, sel, t_ud[safe], t_ul[safe], decay=decay,
+                        fault=fault, deadline=deadline, fault_u=fault_u)

@@ -6,7 +6,13 @@ per-shard; the kernel streams each [C, BLOCK] tile through VMEM once and
 writes one [BLOCK] output tile (HBM traffic = (C+1)/C of the input bytes,
 the roofline minimum).
 
-weights are loaded whole (C <= a few hundred) into VMEM.
+Parameters ride as [C, N/128, 128] (Mosaic's native (8, 128) tiling, so
+the sum over C is whole-vreg adds) and the output as [N/128, 128]; the
+weights are loaded whole (C <= a few hundred) as a [C, 1, 1] block and
+broadcast across the tile: Mosaic's matmul takes no 1-D operand, and a
+C-row weighted sum is VPU work anyway.  Every block is at least 2-D in its
+last two dims, so the kernel stays legal when ``vmap`` (the learning
+engine's grid of runs) adds a batch grid axis in front.
 """
 
 from __future__ import annotations
@@ -17,15 +23,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK = 8192        # output lanes per grid step
+LANES = 128
+BLOCK = 8192        # output elements per grid step: 64 x 128
 
 
 def _fedavg_kernel(x_ref, w_ref, out_ref):
-    x = x_ref[...]                      # [C, BLOCK]
-    w = w_ref[...]                      # [C]
-    acc = jnp.einsum("cb,c->b", x.astype(jnp.float32),
-                     w.astype(jnp.float32))
-    out_ref[...] = acc.astype(out_ref.dtype)
+    x = x_ref[...].astype(jnp.float32)  # [C, BLOCK/128, 128]
+    w = w_ref[...].astype(jnp.float32)  # [C, 1, 1]
+    out_ref[...] = (x * w).sum(0).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -43,15 +48,17 @@ def fedavg_combine(stacked: jnp.ndarray, weights: jnp.ndarray,
     if pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
     c, n = stacked.shape
-    grid = (n // BLOCK,)
-    return pl.pallas_call(
+    rows = BLOCK // LANES
+    out = pl.pallas_call(
         _fedavg_kernel,
-        grid=grid,
+        name="fedavg_combine",
+        grid=(n // BLOCK,),
         in_specs=[
-            pl.BlockSpec((c, BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((c,), lambda i: (0,)),
+            pl.BlockSpec((c, rows, LANES), lambda i: (0, i, 0)),
+            pl.BlockSpec((c, 1, 1), lambda i: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), stacked.dtype),
+        out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // LANES, LANES), stacked.dtype),
         interpret=interpret,
-    )(stacked, weights)[:orig_n]
+    )(stacked.reshape(c, n // LANES, LANES), weights.reshape(c, 1, 1))
+    return out.reshape(n)[:orig_n]

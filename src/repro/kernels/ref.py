@@ -54,9 +54,8 @@ def truncnorm_times_ref(u2, mu_theta, mu_gamma, n_samples, eta, model_bits,
     the expensive op — batching theta+gamma halves its dispatches), then
     t_UD = D_k / gamma, t_UL = M / theta.  Returns ([C] t_ud, [C] t_ul).
 
-    This is the jnp reference of the in-VMEM sampling body of the Pallas
-    bandit-round kernel, and the CPU production path of
-    ``ops.bandit_round_sampled``.
+    Both routes of ``ops.bandit_round_sampled`` draw their [C] times
+    here.
     """
     from repro.sim.truncnorm import truncnorm_transform
 
@@ -133,12 +132,27 @@ def bandit_round_ref(state, cand_idx, t_ud, t_ul, rand, hyper, *,
     safe_slot = jnp.where(ok, slots, 0)
     sel = jnp.where(ok, cand_idx[safe_slot], -1).astype(jnp.int32)
 
-    valid = sel >= 0
-    safe = jnp.where(valid, sel, 0)
     if sliced:
         sud, sul = t_ud[safe_slot], t_ul[safe_slot]
     else:
+        safe = jnp.where(ok, sel, 0)
         sud, sul = t_ud[safe], t_ul[safe]
+    return finish_round(state, sel, sud, sul, decay=decay, fault=fault,
+                        deadline=deadline, fault_u=fault_u)
+
+
+def finish_round(state, sel, sud, sul, *, decay: float = 1.0,
+                 fault: tuple | None = None, deadline: float | None = None,
+                 fault_u=None):
+    """The O(S) tail of every fused round, shared by the compacted
+    reference and the Pallas kernel's route: realized schedule of the
+    selection ``sel`` ([S] client indices, -1 padded) with its gathered
+    times ``sud``/``sul``, the failure layer when ``deadline`` is set, and
+    the ``observe`` scatter.  Returns ``(new_state, sel, round_time)`` —
+    plus the per-slot flags with the failure layer on."""
+    from repro.core import bandit_jax
+
+    valid = sel >= 0
     if deadline is None:
         round_time, incs = bandit_jax.schedule_gathered(valid, sud, sul)
         state = bandit_jax.observe(state, sel, sud, sul, incs, decay=decay)

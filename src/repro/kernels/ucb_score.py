@@ -7,7 +7,9 @@ At datacenter scale the MAB selector scores millions of arms per round
     score_k = BIG                      where n_k == 0   (explore-first)
 
 is elementwise over [K] state arrays — a memory-bound fusion the TPU should
-do in one HBM pass.  Tiled in (8, 128)-aligned 1-D blocks resident in VMEM.
+do in one HBM pass.  The [K] columns ride as [K/128, 128] tiles in
+(32, 128) blocks and the total as a [1, 1] block: Mosaic's native 2-D
+layout, which stays legal when ``vmap`` adds a batch grid axis in front.
 """
 
 from __future__ import annotations
@@ -19,13 +21,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BIG = 1e12
-BLOCK = 4096        # lanes per grid step; multiple of 8*128
+LANES = 128
+BLOCK = 4096        # elements per grid step: 32 x 128
 
 
 def _ucb_kernel(sum_ref, n_ref, total_ref, out_ref, *, alpha: float):
     s = sum_ref[...]
     n = n_ref[...]
-    total = total_ref[0]
+    total = total_ref[0, 0]
     nf = n.astype(jnp.float32)
     safe_n = jnp.maximum(nf, 1.0)
     mean = s / safe_n
@@ -49,18 +52,20 @@ def ucb_scores(sums: jnp.ndarray, n_sel: jnp.ndarray, total: jnp.ndarray,
         sums = jnp.pad(sums, (0, pad))
         n_sel = jnp.pad(n_sel, (0, pad))
     k = sums.shape[0]
-    grid = (k // BLOCK,)
+    rows = BLOCK // LANES
     out = pl.pallas_call(
         functools.partial(_ucb_kernel, alpha=alpha),
-        grid=grid,
+        name="ucb_scores",
+        grid=(k // BLOCK,),
         in_specs=[
-            pl.BlockSpec((BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((k,), jnp.float32),
+        out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((k // LANES, LANES), jnp.float32),
         interpret=interpret,
-    )(sums.astype(jnp.float32), n_sel.astype(jnp.int32),
-      total.reshape(1).astype(jnp.int32))
-    return out[:orig_k]
+    )(sums.astype(jnp.float32).reshape(-1, LANES),
+      n_sel.astype(jnp.int32).reshape(-1, LANES),
+      total.reshape(1, 1).astype(jnp.int32))
+    return out.reshape(k)[:orig_k]

@@ -45,13 +45,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, verbose: bool = True) -> di
     spec = build_cell(arch, shape, mesh)
     # abstract-mesh context so in-model with_sharding_constraint(P(...))
     # hints (e.g. llava's batch-sharded attention) resolve at trace time.
-    # jax < 0.5 has no use_abstract_mesh; the concrete-mesh context still
-    # resolves the explicit in/out shardings, but in-model abstract-mesh
-    # hints (models.layers.constrain_batch) silently no-op there, so the
-    # recorded analysis can differ from a jax >= 0.5 run
-    mesh_ctx = (jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
-                if hasattr(jax.sharding, "use_abstract_mesh") else mesh)
-    with mesh_ctx:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         lowered = jax.jit(spec.fn, in_shardings=spec.in_shardings,
                           out_shardings=spec.out_shardings).lower(
                               *spec.abstract_args)

@@ -29,6 +29,7 @@ import numpy as np
 from repro.checkpoint.ckpt import CheckpointManager
 from repro.sim import async_engine
 from repro.sim.scenarios import Scenario, get_scenario
+from repro.utils.compat import use_compile_cache
 
 _STATE_KEY = "async_serve"
 
@@ -169,6 +170,7 @@ def main(argv=None) -> None:
     ap.add_argument("--max-segments", type=int, default=None,
                     help="stop after N segments (restart smoke tests)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = async_engine.AsyncConfig(
         n_slots=args.n_slots, buffer_size=args.buffer_size,

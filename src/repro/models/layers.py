@@ -209,14 +209,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     return ob.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, KV, G, dh)
 
 
-def _ambient_abstract_mesh():
-    """The ambient abstract mesh, or None when there is none — including on
-    jax < 0.5, where the jax.sharding.get_abstract_mesh context API does not
-    exist at all (sharding is then pinned by the caller's mesh/shard_map)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    return get() if get is not None else None
-
-
 def constrain_batch(x: jnp.ndarray, batch_dim: int = 0) -> jnp.ndarray:
     """Pin activation batch-sharding over the non-model mesh axes.
 
@@ -227,8 +219,8 @@ def constrain_batch(x: jnp.ndarray, batch_dim: int = 0) -> jnp.ndarray:
     activation sharding rules.  No-op outside a mesh context.
     """
     from jax.sharding import PartitionSpec as P
-    mesh = _ambient_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     ba = tuple(a for a in mesh.axis_names if a != "model")
     if not ba:
@@ -254,10 +246,10 @@ def _context_parallel_flash(q, k, v, *, causal, window, kv_valid_len):
     Returns None if no ambient mesh fits (tests without a mesh, tiny
     batches), in which case the caller falls back to the plain path.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    am = _ambient_abstract_mesh()
-    if am is None or "model" not in am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if "model" not in am.axis_names:
         return None
     ba = tuple(a for a in am.axis_names if a != "model")
     n_batch = 1
@@ -280,7 +272,7 @@ def _context_parallel_flash(q, k, v, *, causal, window, kv_valid_len):
         in_specs=(P(ba, "model", None, None, None),
                   P(ba, None, None, None), P(ba, None, None, None), P()),
         out_specs=P(ba, "model", None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, valid)
 
 

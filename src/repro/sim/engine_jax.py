@@ -48,7 +48,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.core import bandit_jax
@@ -773,7 +773,7 @@ def _run_one_clients_segmented(env: EnvArrays, model_bits, hyper, eta, seed,
     out_specs = (P(), P()) if deadline is not None else P()
     return shard_map(body, mesh=client_mesh,
                      in_specs=(P(axis), P(), P(), P(), P()),
-                     out_specs=out_specs, check_rep=False)(
+                     out_specs=out_specs, check_vma=False)(
                          env, model_bits, hyper, eta, seed)
 
 

@@ -22,12 +22,7 @@ for arch, shape in [("smollm-135m", "train_4k"),
                     ("qwen3-1.7b", "decode_32k"),
                     ("recurrentgemma-9b", "long_500k")]:
     spec = build_cell(arch, shape, mesh, reduced=True)
-    # jax < 0.5 has no use_abstract_mesh; the concrete-mesh context still
-    # resolves the explicit in/out shardings, but in-model abstract-mesh
-    # hints (models.layers.constrain_batch) no-op there
-    ctx = (jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
-           if hasattr(jax.sharding, "use_abstract_mesh") else mesh)
-    with ctx:
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         lowered = jax.jit(spec.fn, in_shardings=spec.in_shardings,
                           out_shardings=spec.out_shardings).lower(
                               *spec.abstract_args)
