@@ -169,11 +169,11 @@ def round_parity(k: int, n_req: int, n_rounds: int, policies, *,
                     return st, (sel, rt)
                 t_ud_c, t_ul_c = engine_jax.sample_times_candidates(
                     kt, cand, n_s, theta, gamma, eta, bits)
-                t_ud, t_ul, mask = bandit_jax.scatter_cand_times(
+                t_ud, t_ul = bandit_jax.scatter_cand_times(
                     cand, t_ud_c, t_ul_c, k)
-                st, rt, sel = engine_jax._round(state, mask, t_ud, t_ul,
-                                                select_fn, hyper, kp,
-                                                decay=decay)
+                st, rt, sel = engine_jax._round(
+                    state, bandit_jax.cand_mask(cand, k), t_ud, t_ul,
+                    select_fn, hyper, kp, decay=decay)
                 return st, (sel, rt)
             state, (sels, rts) = jax.lax.scan(
                 step, bandit_jax.BanditState.create(k), keys)

@@ -46,6 +46,18 @@ DEFAULT_BETA = 50.0
 DEFAULT_GAMMA = 0.99    # discounted-UCB decay (core.nonstationary default)
 HIST_WINDOW = 5         # Extended-FedCS moving-average window (paper: 5)
 
+# The four phases of a round, as ``jax.named_scope`` names.  They enter
+# the op_name metadata of the compiled ops (and so the device trace), and
+# cost nothing at run time.  Every round path wraps its phases in them:
+# the Resource Request's candidate draw, the resource times (Eq. 8,
+# scenario multipliers, churn), scoring and selection with the inputs it
+# is fed, and the tail (schedule, failure layer, ``observe``).
+SCOPE_CAND_DRAW = "round.cand_draw"
+SCOPE_TIMES = "round.times"
+SCOPE_SELECT = "round.select"
+SCOPE_FINISH = "round.finish"
+ROUND_SCOPES = (SCOPE_CAND_DRAW, SCOPE_TIMES, SCOPE_SELECT, SCOPE_FINISH)
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -816,16 +828,24 @@ def fused_min_k(policy: str) -> int:
 
 def scatter_cand_times(cand_idx: jnp.ndarray, t_ud_c: jnp.ndarray,
                        t_ul_c: jnp.ndarray, k: int):
-    """Spread candidate-sliced times into zero-[K] buffers plus the [K]
-    candidate mask — the bridge from the streamed-sampling draws to the
+    """Spread candidate-sliced times into zero-[K] buffers — with
+    :func:`cand_mask`, the bridge from the streamed-sampling draws to the
     unfused mask pipeline (``cand_idx`` entries >= K are padding and drop).
     The ONE copy all three fast-path unfused consumers share, so the
     cross-path bitwise-parity gates guard a single definition."""
     drop = jnp.where(cand_idx < k, cand_idx, k)
     t_ud = jnp.zeros(k, jnp.float32).at[drop].set(t_ud_c, mode="drop")
     t_ul = jnp.zeros(k, jnp.float32).at[drop].set(t_ul_c, mode="drop")
-    mask = jnp.zeros(k, bool).at[cand_idx].set(True, mode="drop")
-    return t_ud, t_ul, mask
+    return t_ud, t_ul
+
+
+def cand_mask(cand_idx: jnp.ndarray, k: int) -> jnp.ndarray:
+    """The [K] candidate mask of [C] candidate indices (entries >= K are
+    padding and drop) — the input every mask-fed selection takes, the
+    whole-state kernel's included.  Built here alone, under
+    ``round.select`` whichever path calls it."""
+    with jax.named_scope(SCOPE_SELECT):
+        return jnp.zeros(k, bool).at[cand_idx].set(True, mode="drop")
 
 
 def round_via_mask(state, cand_mask, t_ud, t_ul, rand, hyper, *,
@@ -845,22 +865,25 @@ def round_via_mask(state, cand_mask, t_ud, t_ul, rand, hyper, *,
     consumes the identical randomness and stays bitwise-equal to both the
     fused paths and the engines' ``fused=False`` baseline.
     """
-    sel = _select_with_rand(policy, state, cand_mask, t_ud, t_ul, rand,
-                            hyper, s_round)
-    safe = jnp.where(sel >= 0, sel, 0)
-    if deadline is None:
-        round_time, incs = schedule_selected(sel, t_ud, t_ul)
-        state = observe(state, sel, t_ud[safe], t_ul[safe], incs,
-                        decay=decay)
-        return state, sel, round_time
-    valid = sel >= 0
-    sud, sul = t_ud[safe], t_ul[safe]
-    round_time, incs, finish = schedule_completions(valid, sud, sul)
-    obs_ud, obs_ul, obs_inc, fail, flags, round_time = censor_slots(
-        valid, sud, sul, incs, finish, round_time, fault_u, fault, deadline)
-    state = observe(state, sel, obs_ud, obs_ul, obs_inc, decay=decay,
-                    fail=fail)
-    return state, sel, round_time, flags
+    with jax.named_scope(SCOPE_SELECT):
+        sel = _select_with_rand(policy, state, cand_mask, t_ud, t_ul, rand,
+                                hyper, s_round)
+    with jax.named_scope(SCOPE_FINISH):
+        safe = jnp.where(sel >= 0, sel, 0)
+        if deadline is None:
+            round_time, incs = schedule_selected(sel, t_ud, t_ul)
+            state = observe(state, sel, t_ud[safe], t_ul[safe], incs,
+                            decay=decay)
+            return state, sel, round_time
+        valid = sel >= 0
+        sud, sul = t_ud[safe], t_ul[safe]
+        round_time, incs, finish = schedule_completions(valid, sud, sul)
+        obs_ud, obs_ul, obs_inc, fail, flags, round_time = censor_slots(
+            valid, sud, sul, incs, finish, round_time, fault_u, fault,
+            deadline)
+        state = observe(state, sel, obs_ud, obs_ul, obs_inc, decay=decay,
+                        fail=fail)
+        return state, sel, round_time, flags
 
 
 def make_select_fn(policy: str, s_round: int) -> Callable:
@@ -914,15 +937,17 @@ def make_round_fn(policy: str, s_round: int, *,
     def round_fn(state, cand_idx, key, t_ud, t_ul, hyper):
         from repro.kernels import ops
         k = t_ud.shape[0]
-        # same [K] uniform draw (same key) as select_random_mask, so the
-        # fused and fallback paths consume identical randomness
-        rand = (jax.random.uniform(key, t_ud.shape)
-                if policy == "random" else None)
-        fu = (fault_uniforms(key, s_round)
-              if fault is not None else None)
+        with jax.named_scope(SCOPE_SELECT):
+            # same [K] uniform draw (same key) as select_random_mask, so
+            # the fused and fallback paths consume identical randomness
+            rand = (jax.random.uniform(key, t_ud.shape)
+                    if policy == "random" else None)
+        with jax.named_scope(SCOPE_FINISH):
+            fu = (fault_uniforms(key, s_round)
+                  if fault is not None else None)
         if use_kernel is None and k < fused_min_k(policy):
-            mask = jnp.zeros(k, bool).at[cand_idx].set(True, mode="drop")
-            return round_via_mask(state, mask, t_ud, t_ul, rand, hyper,
+            return round_via_mask(state, cand_mask(cand_idx, k), t_ud, t_ul,
+                                  rand, hyper,
                                   policy=policy, s_round=s_round,
                                   decay=decay, fault=fault,
                                   deadline=deadline, fault_u=fu)
@@ -974,22 +999,27 @@ def make_sampled_round_fn(policy: str, s_round: int, *,
         from repro.kernels import ops
         from repro.kernels.ref import truncnorm_times_ref
         k = theta_mu.shape[0]
-        rand = (jax.random.uniform(key, theta_mu.shape)
-                if policy == "random" else None)
-        u2 = (jax.random.uniform(k_time, (2,) + cand_idx.shape, jnp.float32)
-              if fluctuate else None)
-        fu = (fault_uniforms(key, s_round)
-              if fault is not None else None)
+        with jax.named_scope(SCOPE_SELECT):
+            rand = (jax.random.uniform(key, theta_mu.shape)
+                    if policy == "random" else None)
+        with jax.named_scope(SCOPE_TIMES):
+            u2 = (jax.random.uniform(k_time, (2,) + cand_idx.shape,
+                                     jnp.float32) if fluctuate else None)
+        with jax.named_scope(SCOPE_FINISH):
+            fu = (fault_uniforms(key, s_round)
+                  if fault is not None else None)
         if use_kernel is None and k < fused_min_k(policy):
             # small-K fallback (FUSED_MIN_K): same sliced draws, scattered
             # into zero-[K] buffers for the unfused mask pipeline
-            safe_c = jnp.where(cand_idx < k, cand_idx, 0)
-            t_ud_c, t_ul_c = truncnorm_times_ref(
-                u2, theta_mu[safe_c], gamma_mu[safe_c], n_samples[safe_c],
-                eta, model_bits, fluctuate=fluctuate)
-            t_ud, t_ul, mask = scatter_cand_times(cand_idx, t_ud_c, t_ul_c,
-                                                  k)
-            return round_via_mask(state, mask, t_ud, t_ul, rand, hyper,
+            with jax.named_scope(SCOPE_TIMES):
+                safe_c = jnp.where(cand_idx < k, cand_idx, 0)
+                t_ud_c, t_ul_c = truncnorm_times_ref(
+                    u2, theta_mu[safe_c], gamma_mu[safe_c],
+                    n_samples[safe_c], eta, model_bits, fluctuate=fluctuate)
+                t_ud, t_ul = scatter_cand_times(cand_idx, t_ud_c, t_ul_c,
+                                                k)
+            return round_via_mask(state, cand_mask(cand_idx, k), t_ud, t_ul,
+                                  rand, hyper,
                                   policy=policy, s_round=s_round,
                                   decay=decay, fault=fault,
                                   deadline=deadline, fault_u=fu)
@@ -1082,18 +1112,15 @@ def make_segmented_round_fn(policy: str, s_round: int, *, axis_name: str,
             return jax.lax.psum(
                 jnp.where(in_l, x_c, jnp.zeros((), x_c.dtype)), axis_name)
 
-        u2 = (jax.random.uniform(k_time, (2,) + cand_idx.shape, jnp.float32)
-              if fluctuate else None)
-        t_ud_c, t_ul_c = truncnorm_times_ref(
-            u2, assemble(theta_mu[safe_l]), assemble(gamma_mu[safe_l]),
-            assemble(n_samples[safe_l]), eta, model_bits,
-            fluctuate=fluctuate)
-        rand_c = None
-        if policy == "random":
-            # the flat path's [K] draw, replicated, sliced at the candidates
-            rand = jax.random.uniform(key, (k,))
-            rand_c = rand[jnp.where(cvalid, cand_idx, 0)]
-        fu = fault_uniforms(key, s_round) if fault is not None else None
+        with jax.named_scope(SCOPE_TIMES):
+            u2 = (jax.random.uniform(k_time, (2,) + cand_idx.shape,
+                                     jnp.float32) if fluctuate else None)
+            t_ud_c, t_ul_c = truncnorm_times_ref(
+                u2, assemble(theta_mu[safe_l]), assemble(gamma_mu[safe_l]),
+                assemble(n_samples[safe_l]), eta, model_bits,
+                fluctuate=fluctuate)
+        with jax.named_scope(SCOPE_FINISH):
+            fu = fault_uniforms(key, s_round) if fault is not None else None
 
         def col(name):
             if name == "hist_sum_ud":
@@ -1102,46 +1129,56 @@ def make_segmented_round_fn(policy: str, s_round: int, *, axis_name: str,
                 return state.hist_ul[safe_l].sum(1)
             return getattr(state, name)[safe_l]
 
-        obs_c = {n: col(n) for n in POLICY_STATS[policy]}
-        if kind == "greedy":
-            obs = {n: assemble(v) for n, v in obs_c.items()}
-            _, a, b = policy_scores(policy, obs, state.total,
-                                    state.disc_total, t_ud_c, t_ul_c,
-                                    rand_c, hyper)
-            slots = greedy_slots(a, b, cvalid, s_round)
-        else:
-            # local stats are garbage at foreign slots — masked out before
-            # the local ranking, so only owned slots are ever ranked here
-            _, a, _ = policy_scores(policy, obs_c, state.total,
-                                    state.disc_total, t_ud_c, t_ul_c,
-                                    rand_c, hyper)
-            lvals, lslots = ops.local_topk(
-                jnp.where(in_l, a, -jnp.inf), in_l, s_round,
-                use_kernel=use_kernel, interpret=interpret)
-            gvals = jax.lax.all_gather(lvals, axis_name)
-            gslots = jax.lax.all_gather(lslots, axis_name)
-            slots = segmented_topk_ref(gvals, gslots, s_round)
+        with jax.named_scope(SCOPE_SELECT):
+            rand_c = None
+            if policy == "random":
+                # the flat path's [K] draw, replicated, sliced at the
+                # candidates
+                rand = jax.random.uniform(key, (k,))
+                rand_c = rand[jnp.where(cvalid, cand_idx, 0)]
+            obs_c = {n: col(n) for n in POLICY_STATS[policy]}
+            if kind == "greedy":
+                obs = {n: assemble(v) for n, v in obs_c.items()}
+                _, a, b = policy_scores(policy, obs, state.total,
+                                        state.disc_total, t_ud_c, t_ul_c,
+                                        rand_c, hyper)
+                slots = greedy_slots(a, b, cvalid, s_round)
+            else:
+                # local stats are garbage at foreign slots — masked out
+                # before the local ranking, so only owned slots are ever
+                # ranked here
+                _, a, _ = policy_scores(policy, obs_c, state.total,
+                                        state.disc_total, t_ud_c, t_ul_c,
+                                        rand_c, hyper)
+                lvals, lslots = ops.local_topk(
+                    jnp.where(in_l, a, -jnp.inf), in_l, s_round,
+                    use_kernel=use_kernel, interpret=interpret)
+                gvals = jax.lax.all_gather(lvals, axis_name)
+                gslots = jax.lax.all_gather(lslots, axis_name)
+                slots = segmented_topk_ref(gvals, gslots, s_round)
+            ok = slots >= 0
+            safe_slot = jnp.where(ok, slots, 0)
+            sel = jnp.where(ok, cand_idx[safe_slot], -1).astype(jnp.int32)
 
-        ok = slots >= 0
-        safe_slot = jnp.where(ok, slots, 0)
-        sel = jnp.where(ok, cand_idx[safe_slot], -1).astype(jnp.int32)
-        valid = sel >= 0
-        sud, sul = t_ud_c[safe_slot], t_ul_c[safe_slot]
-        n_valid = valid.sum()
-        # local view of the selection: foreign picks become -1 and drop
-        sel_l = jnp.where(valid & (sel >= off) & (sel < off + k_local),
-                          sel - off, -1)
-        if deadline is None:
-            round_time, incs = schedule_gathered(valid, sud, sul)
-            state2 = observe(state, sel_l, sud, sul, incs, decay=decay,
-                             count_valid=n_valid)
-            return state2, sel, round_time
-        round_time, incs, finish = schedule_completions(valid, sud, sul)
-        obs_ud, obs_ul, obs_inc, fail, flags, round_time = censor_slots(
-            valid, sud, sul, incs, finish, round_time, fu, fault, deadline)
-        state2 = observe(state, sel_l, obs_ud, obs_ul, obs_inc, decay=decay,
-                         fail=fail, count_valid=n_valid)
-        return state2, sel, round_time, flags
+        with jax.named_scope(SCOPE_FINISH):
+            valid = sel >= 0
+            sud, sul = t_ud_c[safe_slot], t_ul_c[safe_slot]
+            n_valid = valid.sum()
+            # local view of the selection: foreign picks become -1 and drop
+            sel_l = jnp.where(valid & (sel >= off) & (sel < off + k_local),
+                              sel - off, -1)
+            if deadline is None:
+                round_time, incs = schedule_gathered(valid, sud, sul)
+                state2 = observe(state, sel_l, sud, sul, incs, decay=decay,
+                                 count_valid=n_valid)
+                return state2, sel, round_time
+            round_time, incs, finish = schedule_completions(valid, sud, sul)
+            obs_ud, obs_ul, obs_inc, fail, flags, round_time = censor_slots(
+                valid, sud, sul, incs, finish, round_time, fu, fault,
+                deadline)
+            state2 = observe(state, sel_l, obs_ud, obs_ul, obs_inc,
+                             decay=decay, fail=fail, count_valid=n_valid)
+            return state2, sel, round_time, flags
 
     return round_fn
 

@@ -555,10 +555,11 @@ def _make_sampled_protocol_round(task: FlTask, hyper, *, policy: str,
             t_ud_c, t_ul_c = engine_jax.sample_times_candidates(
                 k_time, cand, task.env.n_samples, mu_theta, mu_gamma, eta,
                 model_bits, fluctuate=fluctuate)
-            t_ud, t_ul, mask = bandit_jax.scatter_cand_times(cand, t_ud_c,
-                                                             t_ul_c, k)
+            t_ud, t_ul = bandit_jax.scatter_cand_times(cand, t_ud_c,
+                                                       t_ul_c, k)
             out = engine_jax._round(
-                bstate, mask, t_ud, t_ul, select_fn, hyper, k_pol,
+                bstate, bandit_jax.cand_mask(cand, k), t_ud, t_ul,
+                select_fn, hyper, k_pol,
                 decay=decay, fault=fault, deadline=deadline)
             if failure:
                 bstate, round_time, sel, flags = out

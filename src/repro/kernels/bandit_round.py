@@ -154,9 +154,12 @@ def bandit_round_pallas(state, cand_idx, t_ud, t_ul, rand, hyper, *,
     from repro.kernels.ref import finish_round
 
     k = t_ud.shape[0]
-    mask = jnp.zeros(k, bool).at[cand_idx].set(True, mode="drop")
-    sel = select_pallas(state, mask, t_ud, t_ul, rand, hyper, policy=policy,
-                        s_round=s_round, interpret=interpret)
-    safe = jnp.where(sel >= 0, sel, 0)
-    return finish_round(state, sel, t_ud[safe], t_ul[safe], decay=decay,
-                        fault=fault, deadline=deadline, fault_u=fault_u)
+    mask = bandit_jax.cand_mask(cand_idx, k)
+    with jax.named_scope(bandit_jax.SCOPE_SELECT):
+        sel = select_pallas(state, mask, t_ud, t_ul, rand, hyper,
+                            policy=policy, s_round=s_round,
+                            interpret=interpret)
+    with jax.named_scope(bandit_jax.SCOPE_FINISH):
+        safe = jnp.where(sel >= 0, sel, 0)
+        return finish_round(state, sel, t_ud[safe], t_ul[safe], decay=decay,
+                            fault=fault, deadline=deadline, fault_u=fault_u)

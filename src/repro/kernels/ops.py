@@ -105,20 +105,24 @@ def bandit_round_sampled(state, cand_idx, u2, rand, theta_mu, gamma_mu,
     small-K fallback lives in ``core.bandit_jax.make_sampled_round_fn`` —
     see FUSED_MIN_K.)
     """
-    from repro.core.bandit_jax import scatter_cand_times
+    from repro.core.bandit_jax import (SCOPE_SELECT, SCOPE_TIMES,
+                                       scatter_cand_times)
 
     k = theta_mu.shape[0]
-    safe_c = jnp.where(cand_idx < k, cand_idx, 0)
-    t_ud_c, t_ul_c = _ref.truncnorm_times_ref(
-        u2, theta_mu[safe_c], gamma_mu[safe_c], n_samples[safe_c], eta,
-        model_bits, fluctuate=fluctuate)
+    with jax.named_scope(SCOPE_TIMES):
+        safe_c = jnp.where(cand_idx < k, cand_idx, 0)
+        t_ud_c, t_ul_c = _ref.truncnorm_times_ref(
+            u2, theta_mu[safe_c], gamma_mu[safe_c], n_samples[safe_c], eta,
+            model_bits, fluctuate=fluctuate)
     if not _route_kernel(use_kernel, k):
-        rand_c = None if rand is None else rand[safe_c]
+        with jax.named_scope(SCOPE_SELECT):
+            rand_c = None if rand is None else rand[safe_c]
         return _ref.bandit_round_ref(
             state, cand_idx, t_ud_c, t_ul_c, rand_c, hyper, policy=policy,
             s_round=s_round, decay=decay, sliced=True, fault=fault,
             deadline=deadline, fault_u=fault_u)
-    t_ud, t_ul, _ = scatter_cand_times(cand_idx, t_ud_c, t_ul_c, k)
+    with jax.named_scope(SCOPE_TIMES):
+        t_ud, t_ul = scatter_cand_times(cand_idx, t_ud_c, t_ul_c, k)
     interpret = _default_interpret() if interpret is None else interpret
     return _bandit_round.bandit_round_pallas(
         state, cand_idx, t_ud, t_ul, rand, hyper, policy=policy,

@@ -105,40 +105,43 @@ def bandit_round_ref(state, cand_idx, t_ud, t_ul, rand, hyper, *,
     from repro.core import bandit_jax
 
     k = state.n_sel.shape[0]
-    cvalid = cand_idx < k
-    safe_c = jnp.where(cvalid, cand_idx, 0)
+    with jax.named_scope(bandit_jax.SCOPE_SELECT):
+        cvalid = cand_idx < k
+        safe_c = jnp.where(cvalid, cand_idx, 0)
 
-    def col(name):
-        # gather-then-reduce for the ring buffers (same per-row sum as
-        # state_obs's reduce-then-gather, without touching all K rows)
-        if name == "hist_sum_ud":
-            return state.hist_ud[safe_c].sum(1)
-        if name == "hist_sum_ul":
-            return state.hist_ul[safe_c].sum(1)
-        return getattr(state, name)[safe_c]
+        def col(name):
+            # gather-then-reduce for the ring buffers (same per-row sum as
+            # state_obs's reduce-then-gather, without touching all K rows)
+            if name == "hist_sum_ud":
+                return state.hist_ud[safe_c].sum(1)
+            if name == "hist_sum_ul":
+                return state.hist_ul[safe_c].sum(1)
+            return getattr(state, name)[safe_c]
 
-    def at_c(x):
-        return None if x is None else (x if sliced else x[safe_c])
+        def at_c(x):
+            return None if x is None else (x if sliced else x[safe_c])
 
-    obs = {name: col(name) for name in bandit_jax.POLICY_STATS[policy]}
-    kind, a, b = bandit_jax.policy_scores(
-        policy, obs, state.total, state.disc_total,
-        at_c(t_ud), at_c(t_ul), at_c(rand), hyper)
-    if kind == "score":
-        slots = bandit_jax.top_slots(a, cvalid, s_round)
-    else:
-        slots = bandit_jax.greedy_slots(a, b, cvalid, s_round)
-    ok = slots >= 0
-    safe_slot = jnp.where(ok, slots, 0)
-    sel = jnp.where(ok, cand_idx[safe_slot], -1).astype(jnp.int32)
+        obs = {name: col(name)
+               for name in bandit_jax.POLICY_STATS[policy]}
+        kind, a, b = bandit_jax.policy_scores(
+            policy, obs, state.total, state.disc_total,
+            at_c(t_ud), at_c(t_ul), at_c(rand), hyper)
+        if kind == "score":
+            slots = bandit_jax.top_slots(a, cvalid, s_round)
+        else:
+            slots = bandit_jax.greedy_slots(a, b, cvalid, s_round)
+        ok = slots >= 0
+        safe_slot = jnp.where(ok, slots, 0)
+        sel = jnp.where(ok, cand_idx[safe_slot], -1).astype(jnp.int32)
 
-    if sliced:
-        sud, sul = t_ud[safe_slot], t_ul[safe_slot]
-    else:
-        safe = jnp.where(ok, sel, 0)
-        sud, sul = t_ud[safe], t_ul[safe]
-    return finish_round(state, sel, sud, sul, decay=decay, fault=fault,
-                        deadline=deadline, fault_u=fault_u)
+    with jax.named_scope(bandit_jax.SCOPE_FINISH):
+        if sliced:
+            sud, sul = t_ud[safe_slot], t_ul[safe_slot]
+        else:
+            safe = jnp.where(ok, sel, 0)
+            sud, sul = t_ud[safe], t_ul[safe]
+        return finish_round(state, sel, sud, sul, decay=decay, fault=fault,
+                            deadline=deadline, fault_u=fault_u)
 
 
 def finish_round(state, sel, sud, sul, *, decay: float = 1.0,

@@ -183,25 +183,27 @@ def _round(state, cand_mask, t_ud, t_ul, select_fn, hyper, key, decay=1.0,
     draw the fused round makes from the same per-round key, so fused and
     unfused sweeps stay bitwise under faults — and the round returns a
     fourth per-slot ``flags`` output."""
-    sel = select_fn(state, cand_mask, key, t_ud, t_ul, hyper)
-    valid = sel >= 0
-    safe = jnp.where(valid, sel, 0)
-    if deadline is None:
-        round_time, incs = _schedule(sel, t_ud, t_ul)
-        state = bandit_jax.observe(state, sel, t_ud[safe], t_ul[safe], incs,
-                                   decay=decay)
-        return state, round_time, sel
-    fu = (bandit_jax.fault_uniforms(key, sel.shape[0])
-          if fault is not None else None)
-    sud, sul = t_ud[safe], t_ul[safe]
-    round_time, incs, finish = bandit_jax.schedule_completions(valid, sud,
-                                                               sul)
-    obs_ud, obs_ul, obs_inc, fail, flags, round_time = \
-        bandit_jax.censor_slots(valid, sud, sul, incs, finish, round_time,
-                                fu, fault, deadline)
-    state = bandit_jax.observe(state, sel, obs_ud, obs_ul, obs_inc,
-                               decay=decay, fail=fail)
-    return state, round_time, sel, flags
+    with jax.named_scope(bandit_jax.SCOPE_SELECT):
+        sel = select_fn(state, cand_mask, key, t_ud, t_ul, hyper)
+    with jax.named_scope(bandit_jax.SCOPE_FINISH):
+        valid = sel >= 0
+        safe = jnp.where(valid, sel, 0)
+        if deadline is None:
+            round_time, incs = _schedule(sel, t_ud, t_ul)
+            state = bandit_jax.observe(state, sel, t_ud[safe], t_ul[safe],
+                                       incs, decay=decay)
+            return state, round_time, sel
+        fu = (bandit_jax.fault_uniforms(key, sel.shape[0])
+              if fault is not None else None)
+        sud, sul = t_ud[safe], t_ul[safe]
+        round_time, incs, finish = bandit_jax.schedule_completions(
+            valid, sud, sul)
+        obs_ud, obs_ul, obs_inc, fail, flags, round_time = \
+            bandit_jax.censor_slots(valid, sud, sul, incs, finish,
+                                    round_time, fu, fault, deadline)
+        state = bandit_jax.observe(state, sel, obs_ud, obs_ul, obs_inc,
+                                   decay=decay, fail=fail)
+        return state, round_time, sel, flags
 
 
 # ---------------------------------------------------------------------------
@@ -569,36 +571,48 @@ def _run_one(env: EnvArrays, model_bits, hyper, eta, seed,
         def fast_chunk_body(carry, xs):
             state, mean_theta, mean_gamma = carry
             kk, rr = xs
-            cands = _cand_topk_from_keys(kk["cand"], k, n_req)
-            thr_mult = scenario_thr_mult(scen, env.cell_id, kk["cong"], rr)
+            with jax.named_scope(bandit_jax.SCOPE_CAND_DRAW):
+                cands = _cand_topk_from_keys(kk["cand"], k, n_req)
+            with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                thr_mult = scenario_thr_mult(scen, env.cell_id, kk["cong"],
+                                             rr)
 
             if presample:
-                t_ud_cs, t_ul_cs = jax.vmap(
-                    lambda k_t, cand, mult: sample_times_candidates(
-                        k_t, cand, env.n_samples, mean_theta * mult,
-                        mean_gamma, eta, model_bits, fluctuate=fluctuate)
-                )(kk["theta"], cands, thr_mult)
-                # scatter back to [K] (small here by construction) OUTSIDE
-                # the scan — batched like the legacy presample, so the
-                # per-round body carries zero extra ops over the legacy
-                # path; padding lanes hold zeros no selection can reach
-                t_ud_b, t_ul_b, mask_b = jax.vmap(
-                    lambda cand, tu, tl: bandit_jax.scatter_cand_times(
-                        cand, tu, tl, k))(cands, t_ud_cs, t_ul_cs)
+                with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                    t_ud_cs, t_ul_cs = jax.vmap(
+                        lambda k_t, cand, mult: sample_times_candidates(
+                            k_t, cand, env.n_samples, mean_theta * mult,
+                            mean_gamma, eta, model_bits,
+                            fluctuate=fluctuate)
+                    )(kk["theta"], cands, thr_mult)
+                    # scatter back to [K] (small here by construction)
+                    # OUTSIDE the scan — batched like the legacy presample,
+                    # so the per-round body carries zero extra ops over the
+                    # legacy path; padding lanes hold zeros no selection
+                    # can reach
+                    t_ud_b, t_ul_b = jax.vmap(
+                        lambda cand, tu, tl: bandit_jax.scatter_cand_times(
+                            cand, tu, tl, k))(cands, t_ud_cs, t_ul_cs)
 
                 def pre_step(state, x):
                     cand, t_ud, t_ul, kp = x
                     return one_round(state, cand, t_ud, t_ul, kp)
 
+                # the fused round takes the indices, the unfused the masks
+                xs_cand = cands if fused else jax.vmap(
+                    lambda cand: bandit_jax.cand_mask(cand, k))(cands)
+                # the scan's batching moves the grid axis of these xs in a
+                # transpose that XLA fuses with the Eq. 8 draw above; that
+                # fusion is named here, outside any phase (round.other)
                 state, outs = jax.lax.scan(
-                    pre_step, state,
-                    (cands if fused else mask_b, t_ud_b, t_ul_b, kk["pol"]))
+                    pre_step, state, (xs_cand, t_ud_b, t_ul_b, kk["pol"]))
                 return (state, mean_theta, mean_gamma), outs
 
             def step(carry2, x):
                 state, m_theta, m_gamma = carry2
                 cand, mult, k_t, kp, kc = x
-                mu_t = _client_constrain(m_theta * mult, client_mesh)
+                with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                    mu_t = _client_constrain(m_theta * mult, client_mesh)
                 if fused:
                     out = sampled_fn(
                         state, cand, kp, k_t, mu_t, m_gamma, env.n_samples,
@@ -606,19 +620,21 @@ def _run_one(env: EnvArrays, model_bits, hyper, eta, seed,
                     state, obs = out[0], ((out[2], out[3]) if failure
                                           else out[2])
                 else:
-                    t_ud_c, t_ul_c = sample_times_candidates(
-                        k_t, cand, env.n_samples, mu_t, m_gamma, eta,
-                        model_bits, fluctuate=fluctuate)
-                    t_ud, t_ul, mask = bandit_jax.scatter_cand_times(
-                        cand, t_ud_c, t_ul_c, k)
-                    out = _round(state, mask, t_ud, t_ul, select_fn, hyper,
-                                 kp, decay=decay, fault=fault,
-                                 deadline=deadline)
+                    with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                        t_ud_c, t_ul_c = sample_times_candidates(
+                            k_t, cand, env.n_samples, mu_t, m_gamma, eta,
+                            model_bits, fluctuate=fluctuate)
+                        t_ud, t_ul = bandit_jax.scatter_cand_times(
+                            cand, t_ud_c, t_ul_c, k)
+                    out = _round(state, bandit_jax.cand_mask(cand, k), t_ud,
+                                 t_ul, select_fn, hyper, kp, decay=decay,
+                                 fault=fault, deadline=deadline)
                     state, obs = out[0], ((out[1], out[3]) if failure
                                           else out[1])
                 if scen.churn_prob > 0.0:
-                    m_theta, m_gamma = churn_step(kc, m_theta, m_gamma,
-                                                  scen.churn_prob)
+                    with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                        m_theta, m_gamma = churn_step(kc, m_theta, m_gamma,
+                                                      scen.churn_prob)
                 return (state, m_theta, m_gamma), obs
 
             carry2, outs = jax.lax.scan(
@@ -633,16 +649,19 @@ def _run_one(env: EnvArrays, model_bits, hyper, eta, seed,
     def chunk_body(carry, xs):
         state, mean_theta, mean_gamma = carry
         kk, rr = xs
-        cands = round_cands(kk["cand"])
-        thr_mult = scenario_thr_mult(scen, env.cell_id, kk["cong"], rr)
+        with jax.named_scope(bandit_jax.SCOPE_CAND_DRAW):
+            cands = round_cands(kk["cand"])
+        with jax.named_scope(bandit_jax.SCOPE_TIMES):
+            thr_mult = scenario_thr_mult(scen, env.cell_id, kk["cong"], rr)
 
         if scen.churn_prob == 0.0:
             # stateless resources: pre-sample the whole chunk in one shot
-            t_ud, t_ul = _client_constrain(sample_times_rounds(
-                env.n_samples, mean_theta[None, :] * thr_mult,
-                jnp.broadcast_to(mean_gamma, (c, k)), eta, model_bits,
-                kk["theta"], kk["gamma"], fluctuate=fluctuate), client_mesh,
-                client_dim=1)
+            with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                t_ud, t_ul = _client_constrain(sample_times_rounds(
+                    env.n_samples, mean_theta[None, :] * thr_mult,
+                    jnp.broadcast_to(mean_gamma, (c, k)), eta, model_bits,
+                    kk["theta"], kk["gamma"], fluctuate=fluctuate),
+                    client_mesh, client_dim=1)
 
             def step(state, x):
                 cand, t_ud_r, t_ul_r, kp = x
@@ -655,12 +674,14 @@ def _run_one(env: EnvArrays, model_bits, hyper, eta, seed,
         def step(carry2, x):
             state, m_theta, m_gamma = carry2
             cand, mult, k_t, k_g, kp, kc = x
-            t_ud, t_ul = sample_times(env.n_samples, m_theta * mult,
-                                      m_gamma, eta, model_bits, k_t, k_g,
-                                      fluctuate=fluctuate)
+            with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                t_ud, t_ul = sample_times(env.n_samples, m_theta * mult,
+                                          m_gamma, eta, model_bits, k_t,
+                                          k_g, fluctuate=fluctuate)
             state, obs = one_round(state, cand, t_ud, t_ul, kp)
-            m_theta, m_gamma = churn_step(kc, m_theta, m_gamma,
-                                          scen.churn_prob)
+            with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                m_theta, m_gamma = churn_step(kc, m_theta, m_gamma,
+                                              scen.churn_prob)
             return (state, m_theta, m_gamma), obs
 
         carry2, outs = jax.lax.scan(
@@ -724,19 +745,23 @@ def _segmented_sweep_body(env: EnvArrays, model_bits, hyper, eta, seed,
             kc, kcong, k_t, kp, kch, r1 = x
             # replicated global candidate draw — the [K] uniforms exist
             # only transiently inside this round, never as a [R', K] batch
-            cand = _cand_topk_one(kc, k, n_req)
+            with jax.named_scope(bandit_jax.SCOPE_CAND_DRAW):
+                cand = _cand_topk_one(kc, k, n_req)
             # local slice of the global multiplier (cell normals are drawn
             # replicated, then indexed by this shard's cell_id block)
-            mult = scenario_thr_mult(scen, env.cell_id, kcong[None],
-                                     r1[None])[0]
-            out = seg_fn(state, cand, kp, k_t, m_theta * mult, m_gamma,
+            with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                mult = scenario_thr_mult(scen, env.cell_id, kcong[None],
+                                         r1[None])[0]
+                mu_t = m_theta * mult
+            out = seg_fn(state, cand, kp, k_t, mu_t, m_gamma,
                          env.n_samples, eta, model_bits, hyper)
             state = out[0]
             obs = (out[2], out[3]) if failure else out[2]
             if scen.churn_prob > 0.0:
-                m_theta, m_gamma = churn_step(kch, m_theta, m_gamma,
-                                              scen.churn_prob, k_total=k,
-                                              offset=off)
+                with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                    m_theta, m_gamma = churn_step(
+                        kch, m_theta, m_gamma, scen.churn_prob, k_total=k,
+                        offset=off)
             return (state, m_theta, m_gamma), obs
 
         return jax.lax.scan(
@@ -830,35 +855,42 @@ def _run_one_hier(env: EnvArrays, model_bits, hyper, eta, seed,
         def step(carry2, x):
             state, m_theta, m_gamma, cell_n, cell_tinc = carry2
             kc, kcong, k_t, kp, kch, r1 = x
-            cells_sel = bandit_jax.select_cells(cell_n, cell_tinc, s_cells)
-            cand = bandit_jax.hier_cand_idx(kc, cells_sel, k, n_cells,
-                                            n_req_cell)
-            mult = scenario_thr_mult(scen, env.cell_id, kcong[None],
-                                     r1[None])[0]
-            mu_t = m_theta * mult
+            with jax.named_scope(bandit_jax.SCOPE_SELECT):
+                cells_sel = bandit_jax.select_cells(cell_n, cell_tinc,
+                                                    s_cells)
+            with jax.named_scope(bandit_jax.SCOPE_CAND_DRAW):
+                cand = bandit_jax.hier_cand_idx(kc, cells_sel, k, n_cells,
+                                                n_req_cell)
+            with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                mult = scenario_thr_mult(scen, env.cell_id, kcong[None],
+                                         r1[None])[0]
+                mu_t = m_theta * mult
             if fused:
                 out = sampled_fn(state, cand, kp, k_t, mu_t, m_gamma,
                                  env.n_samples, eta, model_bits, hyper)
                 state2, sel = out[0], out[1]
                 obs = (out[2], out[3]) if failure else out[2]
             else:
-                t_ud_c, t_ul_c = sample_times_candidates(
-                    k_t, cand, env.n_samples, mu_t, m_gamma, eta,
-                    model_bits, fluctuate=fluctuate)
-                t_ud, t_ul, mask = bandit_jax.scatter_cand_times(
-                    cand, t_ud_c, t_ul_c, k)
-                out = _round(state, mask, t_ud, t_ul, select_fn, hyper,
-                             kp, decay=decay, fault=fault,
-                             deadline=deadline)
+                with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                    t_ud_c, t_ul_c = sample_times_candidates(
+                        k_t, cand, env.n_samples, mu_t, m_gamma, eta,
+                        model_bits, fluctuate=fluctuate)
+                    t_ud, t_ul = bandit_jax.scatter_cand_times(
+                        cand, t_ud_c, t_ul_c, k)
+                out = _round(state, bandit_jax.cand_mask(cand, k), t_ud,
+                             t_ul, select_fn, hyper, kp, decay=decay,
+                             fault=fault, deadline=deadline)
                 state2, sel = out[0], out[2]
                 obs = (out[1], out[3]) if failure else out[1]
-            cell_n, cell_tinc = bandit_jax.update_cell_stats(
-                cell_n, cell_tinc, sel, state.sum_tinc, state2.sum_tinc,
-                env.cell_id, n_cells)
+            with jax.named_scope(bandit_jax.SCOPE_FINISH):
+                cell_n, cell_tinc = bandit_jax.update_cell_stats(
+                    cell_n, cell_tinc, sel, state.sum_tinc, state2.sum_tinc,
+                    env.cell_id, n_cells)
             state = state2
             if scen.churn_prob > 0.0:
-                m_theta, m_gamma = churn_step(kch, m_theta, m_gamma,
-                                              scen.churn_prob)
+                with jax.named_scope(bandit_jax.SCOPE_TIMES):
+                    m_theta, m_gamma = churn_step(kch, m_theta, m_gamma,
+                                                  scen.churn_prob)
             return (state, m_theta, m_gamma, cell_n, cell_tinc), obs
 
         return jax.lax.scan(
@@ -993,6 +1025,16 @@ def resolve_sweep_mesh(devices) -> "jax.sharding.Mesh | None":
     return None if mesh.size == 1 else mesh
 
 
+# host spans of one sweep() call (jax.profiler.TraceAnnotation), in order;
+# sweep.put and sweep.fetch carry the bytes they move as ``bytes``
+SWEEP_SPANS = ("sweep.env", "sweep.put", "sweep.run", "sweep.fetch")
+
+
+def _nbytes(tree) -> int:
+    """Summed ``nbytes`` of the arrays in ``tree`` (from shapes alone)."""
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
+
+
 def sweep(scenario: Scenario | str = "paper-baseline",
           policies=tuple(bandit_jax.POLICY_NAMES),
           etas=(1.0, 1.5, 1.9),
@@ -1088,6 +1130,22 @@ def sweep(scenario: Scenario | str = "paper-baseline",
     candidates (core.bandit_jax.make_segmented_round_fn) — O(K/P) state
     and O(K/P + C) work per device, bitwise the flat selections.  Any
     other clients-mode configuration keeps the GSPMD-constraint layout.
+
+    Tracing: run the call under ``jax.profiler.trace(dir)`` and the trace
+    holds four host spans per call, in this order (``SWEEP_SPANS``):
+    ``sweep.env`` (the scenario's env build), ``sweep.put`` (every
+    host-to-device transfer, with argument ``bytes``), ``sweep.run`` (the
+    one jit call: dispatch, and the compile on a cache miss) and
+    ``sweep.fetch`` (the copy of the results to the host, which waits for
+    the device, with argument ``bytes``).  ``bytes`` is the summed
+    ``nbytes`` of the arrays moved.  On the device side every op of the
+    round loop carries one of four name scopes in its op_name
+    (``core.bandit_jax.ROUND_SCOPES``): ``round.cand_draw`` (the Resource
+    Request's candidate draw), ``round.times`` (Eq. 8 times, scenario
+    multipliers, churn), ``round.select`` (scoring and Algorithm 1 /
+    top-S, with the inputs the selection kernels are fed) and
+    ``round.finish`` (schedule, failure layer, ``observe``).  Scopes are
+    compile-time metadata and spans cost nothing while no trace runs.
     """
     scenario = get_scenario(scenario) if isinstance(scenario, str) else scenario
     if shard not in ("grid", "clients"):
@@ -1146,8 +1204,8 @@ def sweep(scenario: Scenario | str = "paper-baseline",
         and hierarchy == "flat"
         and dist_sharding.even_shards(n_clients, mesh) is not None)
 
-    env = scenario.build_env(n_clients, np.random.default_rng(env_seed))
-    env_arrays = EnvArrays.from_scenario(scenario, env)
+    with jax.profiler.TraceAnnotation("sweep.env"):
+        env = scenario.build_env(n_clients, np.random.default_rng(env_seed))
 
     # flatten the shared (E, S) axes; the policy axis stays static
     grid_e, grid_s = np.meshgrid(np.arange(len(etas)), np.arange(len(seeds)),
@@ -1155,30 +1213,38 @@ def sweep(scenario: Scenario | str = "paper-baseline",
     g_eta = np.array(etas, np.float32)[grid_e.ravel()]
     g_seed = np.array(seeds, np.int64)[grid_s.ravel()]
     n_grid = len(g_eta)
-
     if mesh is not None and shard == "grid":
         g_eta = dist_sharding.pad_leading(g_eta, mesh.size)
         g_seed = dist_sharding.pad_leading(g_seed, mesh.size)
-    if mesh is not None and shard == "clients":
-        env_arrays = dist_sharding.shard_leading(env_arrays, mesh)
 
-    with suppress_unusable_donation_warnings():
+    with jax.profiler.TraceAnnotation("sweep.put") as span:
+        env_arrays = EnvArrays.from_scenario(scenario, env)
+        if mesh is not None and shard == "clients":
+            env_arrays = dist_sharding.shard_leading(env_arrays, mesh)
+        args = (env_arrays, jnp.float32(model_bits),
+                jnp.asarray(hypers, jnp.float32), jnp.asarray(g_eta),
+                jnp.asarray(g_seed))
+        span.set_metadata(bytes=_nbytes(args))
+
+    with jax.profiler.TraceAnnotation("sweep.run"), \
+            suppress_unusable_donation_warnings():
+        # eta and seed (args[3:]) are donated: nothing reads them after
         out = _run_grid(
-            env_arrays, jnp.float32(model_bits),
-            jnp.asarray(hypers, jnp.float32), jnp.asarray(g_eta),
-            jnp.asarray(g_seed),
-            policies=tuple(pol_names), scen=scenario, n_rounds=n_rounds,
-            s_round=s_round, n_req=n_req,
+            *args, policies=tuple(pol_names), scen=scenario,
+            n_rounds=n_rounds, s_round=s_round, n_req=n_req,
             fluctuate=fluctuate, chunk_rounds=chunk_rounds, mesh=mesh,
             shard=shard, fused=fused, fast_sampling=fast_sampling,
             deadline=deadline, segmented=use_segmented,
             hierarchy=hierarchy, s_cells=s_cells_arg,
             n_req_cell=n_req_cell)
-    rts, flags = out if deadline is not None else (out, None)
-    rts = np.asarray(rts)[:, :n_grid].reshape(
-        len(pol_names), len(etas), len(seeds), n_rounds)
-    if flags is not None:
-        flags = np.asarray(flags)[:, :n_grid].reshape(
-            len(pol_names), len(etas), len(seeds), n_rounds, s_round)
+
+    with jax.profiler.TraceAnnotation("sweep.fetch") as span:
+        span.set_metadata(bytes=_nbytes(out))
+        rts, flags = out if deadline is not None else (out, None)
+        rts = np.asarray(rts)[:, :n_grid].reshape(
+            len(pol_names), len(etas), len(seeds), n_rounds)
+        if flags is not None:
+            flags = np.asarray(flags)[:, :n_grid].reshape(
+                len(pol_names), len(etas), len(seeds), n_rounds, s_round)
     return SweepResult(policies=tuple(pol_names), hypers=tuple(hypers),
                        etas=etas, seeds=seeds, round_times=rts, flags=flags)

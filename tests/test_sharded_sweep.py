@@ -148,6 +148,20 @@ def test_multidevice_segmented_bitwise():
 
 
 @needs_devices
+def test_multidevice_segmented_names_round_scopes():
+    """The segmented round loop names the round's four phases in its ops
+    (the merge's psum and all-gather under ``round.select``)."""
+    from _scopes import compiled_sweep_text, round_scopes
+    from repro.core import bandit_jax
+    kw = dict(n_rounds=4, n_clients=2048, seeds=2, etas=(1.5,),
+              policies=("elementwise_ucb", "naive_ucb"), frac_request=0.05,
+              devices=jax.device_count(), shard="clients")
+    text = compiled_sweep_text("correlated-congestion", **kw)
+    assert round_scopes(text) == set(bandit_jax.ROUND_SCOPES)
+    assert re.search(r'all-gather[^\n]*op_name="[^"]*round\.select', text)
+
+
+@needs_devices
 def test_multidevice_segmented_faults_large_k():
     """Faults-on segmented fused round at K=1e5: censored observations,
     deadline clock, and per-slot flags all bitwise across the shards."""

@@ -80,7 +80,8 @@ def _batched(sharding, spec, g=3):
 
 
 class _Compiled(Exception):
-    """Carries the text of a program compiled in place of running it."""
+    """Carries the text of a program compiled in place of running it (and,
+    from ``tpu_routes``, the compiled program)."""
 
 
 @pytest.fixture
@@ -102,7 +103,8 @@ def tpu_routes(monkeypatch, one_chip):
         def compile_call(*args, **static):
             args = jax.tree.map(
                 lambda x: _spec(one_chip, x.shape, x.dtype), args)
-            raise _Compiled(fn.lower(*args, **static).compile().as_text())
+            compiled = fn.lower(*args, **static).compile()
+            raise _Compiled(compiled.as_text(), compiled)
         monkeypatch.setattr(module, name, compile_call)
 
     yield compile_only
@@ -237,3 +239,69 @@ def test_accuracy_sweep_program_compiles(tpu_routes):
                                  seeds=2, n_rounds=2, s_round=3, cfg=cfg,
                                  epochs=1, batch_size=10, use_kernel=True)
     assert "fedavg_combine" in compiled.value.args[0]
+
+
+def _hlo_proto(compiled) -> bytes:
+    """The compiled module as the ``HloProto`` a TPU trace stores (the
+    module is its field 1)."""
+    module = compiled.runtime_executable().hlo_modules()[0]
+    body = module.as_serialized_hlo_module_proto()
+    size, n = bytearray(), len(body)
+    while True:
+        size.append((n & 0x7F) | (0x80 if n >> 7 else 0))
+        n >>= 7
+        if not n:
+            break
+    return b"\x0a" + bytes(size) + body
+
+
+def test_sweep_program_scatters_take_their_phase(tpu_routes):
+    """The round phase the trace reader (chipbench/scopes.py) gives each
+    scatter of the crossdevice-1e5 program (K = 10^5, C = 10^4, the
+    whole-state kernel route), and the fusion it is the root of.  XLA
+    leaves most of them, and the index preparation that feeds them,
+    without an op_name of their own, so the reader charges them by their
+    data.  Each must land in the phase it was written in: the [G*K]
+    candidate masks in round.select (bandit_jax.cand_mask), the [G*K]
+    times in round.times (scatter_cand_times), and the [G, K] statistics
+    indexed by the kernel's selection in round.finish (observe)."""
+    import re
+    import sys
+    from pathlib import Path
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from chipbench import scopes
+
+    tpu_routes(engine_jax, "_run_grid")
+    k, g = 100_000, 2
+    with pytest.raises(_Compiled) as compiled:
+        engine_jax.sweep(n_clients=k, frac_request=0.1, s_round=S_ROUND,
+                         policies=("elementwise_ucb", "fedcs", "random"),
+                         etas=(1.5,), seeds=g, n_rounds=4)
+    text, program = compiled.value.args
+    proto = _hlo_proto(program)
+    phases = scopes._module_phases(proto, (0, len(proto)))
+    fused_in = {m.group(2): m.group(1) for m in re.finditer(
+        r"%(fusion[\w.\-]*) = .* calls=%([\w.\-]+)", text)}
+    comp, want = None, {}
+    for line in text.splitlines():
+        head = re.match(r"%([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+        m = re.match(r"\s*(ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                     r"scatter\(%[\w.\-]+, %([\w.\-]+)", line)
+        if not m:
+            continue
+        root, name, dtype, dims, indices = m.groups()
+        if dims == f"{g * k}":
+            phase = "round.select" if dtype == "pred" else "round.times"
+        else:
+            assert dims == f"{g},{k}" and indices.startswith("custom-call")
+            phase = "round.finish"
+        want[name] = phase
+        if root and comp in fused_in:
+            want[fused_in[comp]] = phase
+    # one mask and two times per policy, some statistics per policy
+    counts = {p: list(want.values()).count(p) for p in set(want.values())}
+    assert counts["round.select"] >= 3 and counts["round.times"] >= 6
+    assert counts["round.finish"] >= 3
+    assert {name: phases[name] for name in want} == want
