@@ -594,6 +594,12 @@ POLICY_STATS: dict[str, tuple[str, ...]] = {
     "discounted_ucb": ("disc_n", "disc_ud", "disc_ul"),
     "sliding_ucb": ("hist_sum_ud", "hist_sum_ul", "hist_n", "n_sel"),
 }
+# Policies whose scores read this round's realised times (``t_ud``/``t_ul``
+# of :func:`policy_scores`).  The others score from bandit statistics
+# alone, so the streamed sampler's kernel route draws Eq. 8 for them only
+# at the S selected clients, after the selection
+# (kernels/ops.bandit_round_sampled).
+POLICY_READS_TIMES: frozenset[str] = frozenset({"oracle"})
 
 
 def policy_kind(policy: str) -> str:
@@ -830,8 +836,9 @@ def scatter_cand_times(cand_idx: jnp.ndarray, t_ud_c: jnp.ndarray,
                        t_ul_c: jnp.ndarray, k: int):
     """Spread candidate-sliced times into zero-[K] buffers — with
     :func:`cand_mask`, the bridge from the streamed-sampling draws to the
-    unfused mask pipeline (``cand_idx`` entries >= K are padding and drop).
-    The ONE copy all three fast-path unfused consumers share, so the
+    unfused mask pipeline and to the whole-state kernel of a policy that
+    reads the times (:data:`POLICY_READS_TIMES`; ``cand_idx`` entries >= K
+    are padding and drop).  The ONE copy all these consumers share, so the
     cross-path bitwise-parity gates guard a single definition."""
     drop = jnp.where(cand_idx < k, cand_idx, k)
     t_ud = jnp.zeros(k, jnp.float32).at[drop].set(t_ud_c, mode="drop")
@@ -977,9 +984,13 @@ def make_sampled_round_fn(policy: str, s_round: int, *,
     this round's time-draw PRNG key.  The round never materializes [K]
     resource draws: it draws ONE [2, C] uniform block from ``k_time``
     (bitwise the stream of sim.engine_jax.sample_times_candidates with the
-    same key) and the transform to (t_UD, t_UL) runs on that [C] slice
-    inside the fused round (kernels/ops.bandit_round_sampled routes the
-    selection to the Pallas kernel on TPU, the jnp reference elsewhere).
+    same key) and the transform to (t_UD, t_UL) runs inside the fused round
+    (kernels/ops.bandit_round_sampled routes the selection to the Pallas
+    kernel on TPU, the jnp reference elsewhere).  The kernel route draws
+    Eq. 8 at the S selected slots unless the policy reads the times
+    (:data:`POLICY_READS_TIMES`); the reference route and a time-reading
+    policy transform the whole [C] slice.  A client's time is the same
+    either way.
 
     The random policy still draws its [K] uniform stream from ``key`` so
     the fast path's fused and unfused executions stay bitwise-identical,

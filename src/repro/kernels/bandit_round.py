@@ -119,10 +119,12 @@ def select_pallas(state, mask, t_ud, t_ul, rand, hyper, *, policy: str,
     """Whole-state selection: [K] candidate ``mask`` -> [S] client indices
     (-1 padded), bitwise the mask-based select fns.  Only the columns the
     policy reads (``core.bandit_jax.POLICY_STATS``) enter the kernel; the
-    ring-buffer sums are reduced over the window in XLA first."""
+    ring-buffer sums are reduced over the window in XLA first.
+    ``t_ud``/``t_ul`` are read only for the policies in
+    ``core.bandit_jax.POLICY_READS_TIMES`` and may be None for the rest."""
     obs = bandit_jax.state_obs(state)
     cols = {n: obs[n] for n in bandit_jax.POLICY_STATS[policy]}
-    if policy == "oracle":
+    if policy in bandit_jax.POLICY_READS_TIMES:
         cols.update(t_ud=t_ud, t_ul=t_ul)
     if policy == "random":
         cols["rand"] = rand

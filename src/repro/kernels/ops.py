@@ -91,43 +91,66 @@ def bandit_round_sampled(state, cand_idx, u2, rand, theta_mu, gamma_mu,
                          deadline: float | None = None,
                          fault_u=None):
     """The streamed-sampling fused round: Eq. (8) resource times are drawn
-    AT THE CANDIDATE SLICE inside the round instead of arriving as [K]
-    arrays; returns ``(new_state, sel, round_time)``.
+    inside the round from one [2, C] uniform block instead of arriving as
+    [K] arrays; returns ``(new_state, sel, round_time)``.
 
-    ``u2``: [2, C] uniforms (None when ``fluctuate`` is off);
-    ``theta_mu``/``gamma_mu``/``n_samples``: full-[K] means (``theta_mu``
-    carries any scenario multiplier); ``rand``: the random policy's [K]
-    uniform stream (None otherwise).  Either route gathers the [C] slice
-    and transforms it via ``kernels/ref.truncnorm_times_ref``; routing
-    mirrors ``bandit_round``: the kernel route spreads the times into [K]
-    buffers (``core.bandit_jax.scatter_cand_times``) for the whole-state
-    Pallas selection, the other runs the sliced jnp reference.  (The
-    small-K fallback lives in ``core.bandit_jax.make_sampled_round_fn`` —
-    see FUSED_MIN_K.)
+    ``u2``: [2, C] uniforms (None when ``fluctuate`` is off), column j
+    belonging to candidate ``cand_idx[j]``; ``theta_mu``/``gamma_mu``/
+    ``n_samples``: full-[K] means (``theta_mu`` carries any scenario
+    multiplier); ``rand``: the random policy's [K] uniform stream (None
+    otherwise).  The times come from ``kernels/ref.truncnorm_times_ref``,
+    elementwise, so a client's time is the same wherever it is drawn.
+    Routing mirrors ``bandit_round``.  The kernel route draws Eq. 8 at the
+    S selected slots, after the whole-state Pallas selection; only a
+    policy that reads the times (``core.bandit_jax.POLICY_READS_TIMES``)
+    also draws the [C] slice first and spreads it into [K] buffers
+    (``core.bandit_jax.scatter_cand_times``) for the kernel.  The other
+    route draws the [C] slice for the sliced jnp reference.  (The small-K
+    fallback lives in ``core.bandit_jax.make_sampled_round_fn`` — see
+    FUSED_MIN_K.)
     """
-    from repro.core.bandit_jax import (SCOPE_SELECT, SCOPE_TIMES,
+    from repro.core.bandit_jax import (POLICY_READS_TIMES, SCOPE_FINISH,
+                                       SCOPE_SELECT, SCOPE_TIMES, cand_mask,
                                        scatter_cand_times)
 
     k = theta_mu.shape[0]
+
+    def times_at(idx, u):
+        return _ref.truncnorm_times_ref(
+            u, theta_mu[idx], gamma_mu[idx], n_samples[idx], eta,
+            model_bits, fluctuate=fluctuate)
+
     with jax.named_scope(SCOPE_TIMES):
         safe_c = jnp.where(cand_idx < k, cand_idx, 0)
-        t_ud_c, t_ul_c = _ref.truncnorm_times_ref(
-            u2, theta_mu[safe_c], gamma_mu[safe_c], n_samples[safe_c], eta,
-            model_bits, fluctuate=fluctuate)
     if not _route_kernel(use_kernel, k):
+        with jax.named_scope(SCOPE_TIMES):
+            t_ud_c, t_ul_c = times_at(safe_c, u2)
         with jax.named_scope(SCOPE_SELECT):
             rand_c = None if rand is None else rand[safe_c]
         return _ref.bandit_round_ref(
             state, cand_idx, t_ud_c, t_ul_c, rand_c, hyper, policy=policy,
             s_round=s_round, decay=decay, sliced=True, fault=fault,
             deadline=deadline, fault_u=fault_u)
-    with jax.named_scope(SCOPE_TIMES):
-        t_ud, t_ul = scatter_cand_times(cand_idx, t_ud_c, t_ul_c, k)
+    t_ud = t_ul = None
+    if policy in POLICY_READS_TIMES:
+        with jax.named_scope(SCOPE_TIMES):
+            t_ud, t_ul = scatter_cand_times(cand_idx, *times_at(safe_c, u2),
+                                            k)
     interpret = _default_interpret() if interpret is None else interpret
-    return _bandit_round.bandit_round_pallas(
-        state, cand_idx, t_ud, t_ul, rand, hyper, policy=policy,
-        s_round=s_round, decay=decay, interpret=interpret, fault=fault,
-        deadline=deadline, fault_u=fault_u)
+    with jax.named_scope(SCOPE_SELECT):
+        sel = _bandit_round.select_pallas(
+            state, cand_mask(cand_idx, k), t_ud, t_ul, rand, hyper,
+            policy=policy, s_round=s_round, interpret=interpret)
+    with jax.named_scope(SCOPE_TIMES):
+        # each selected client's column of u2: its slot in the sorted
+        # cand_idx (a -1 slot reads slot 0 and stays invalid)
+        safe = jnp.where(sel >= 0, sel, 0)
+        slot = jnp.searchsorted(cand_idx, safe, method="compare_all")
+        sud, sul = times_at(safe, None if u2 is None else u2[:, slot])
+    with jax.named_scope(SCOPE_FINISH):
+        return _ref.finish_round(state, sel, sud, sul, decay=decay,
+                                 fault=fault, deadline=deadline,
+                                 fault_u=fault_u)
 
 
 def local_topk(score, valid, s_round: int, *,

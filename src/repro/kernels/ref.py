@@ -44,18 +44,21 @@ def flash_attention_ref(q, k, v, causal: bool = True):
 
 def truncnorm_times_ref(u2, mu_theta, mu_gamma, n_samples, eta, model_bits,
                         *, fluctuate: bool = True):
-    """Eqs. (8)-(11) at the candidate slice: ONE fused two-draw transform.
+    """Eqs. (8)-(11) at the candidate slice: ONE fused two-draw transform,
+    elementwise per client.
 
-    ``u2``: [2, C] uniforms (row 0 -> throughput theta, row 1 -> capability
+    ``u2``: [2, N] uniforms (row 0 -> throughput theta, row 1 -> capability
     gamma — the layout ``make_sampled_round_fn``'s single ``[2, C]``
-    uniform call produces); ``mu_theta``/``mu_gamma``/``n_samples``: [C]
-    candidate-gathered means.  Both truncated normals run through one
-    stacked :func:`repro.sim.truncnorm.truncnorm_transform` call (erfinv is
-    the expensive op — batching theta+gamma halves its dispatches), then
-    t_UD = D_k / gamma, t_UL = M / theta.  Returns ([C] t_ud, [C] t_ul).
+    uniform call produces, or N of its columns); ``mu_theta``/``mu_gamma``/
+    ``n_samples``: the [N] gathered means.  Both truncated normals run
+    through one stacked :func:`repro.sim.truncnorm.truncnorm_transform`
+    call (erfinv is the expensive op — batching theta+gamma halves its
+    dispatches), then t_UD = D_k / gamma, t_UL = M / theta.  Returns
+    ([N] t_ud, [N] t_ul).
 
-    Both routes of ``ops.bandit_round_sampled`` draw their [C] times
-    here.
+    Every route of ``ops.bandit_round_sampled`` draws its times here: the
+    whole [C] slice, or the S selected columns on the kernel route of a
+    policy that does not read the times.
     """
     from repro.sim.truncnorm import truncnorm_transform
 

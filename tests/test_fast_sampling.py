@@ -211,12 +211,23 @@ def test_sampled_round_consumes_sample_times_candidates_stream():
         np.asarray(state.last_ud)[safe], np.asarray(t_ud)[safe])
 
 
-@pytest.mark.parametrize("policy", bandit_jax.POLICY_NAMES)
-def test_sampled_kernel_interpret_matches_ref(policy):
+@pytest.mark.parametrize("policy, case", [
+    *(pytest.param(p, None, id=p) for p in bandit_jax.POLICY_NAMES),
+    *(pytest.param(p, c, id=f"{p}-{c}") for c in ("faults", "exhausted")
+      for p in bandit_jax.POLICY_NAMES)])
+def test_sampled_kernel_interpret_matches_ref(policy, case):
     """Pallas sampled kernel (in-VMEM Eq. 8 transform, interpret mode) vs
     the sliced jnp reference: bitwise on selections, round times and the
-    full state, for all 8 policies."""
-    k, s_round, n_cand, rounds = 70, 4, 20, 5
+    full state, for all 8 policies.  Two more cases reach the inputs of
+    finish_round that the kernel route's per-slot draw feeds: the failure
+    layer (fault uniforms and a deadline inside the spread of these
+    clients' round times, so some slots meet it and some miss it; flags
+    compared too), and fewer candidates than slots, so that exhausted
+    (-1) slots occur."""
+    k, s_round, rounds = 70, 4, 5
+    n_cand = 3 if case == "exhausted" else 20
+    kw = (dict(fault=(0.2, 0.1, 0.1), deadline=800.0) if case == "faults"
+          else {})
     kc, kt, kp_, ke = jax.random.split(jax.random.PRNGKey(2), 4)
     cand = engine_jax._cand_topk_from_keys(
         jax.random.split(kc, rounds), k, n_cand)
@@ -230,21 +241,57 @@ def test_sampled_kernel_interpret_matches_ref(policy):
 
     hyper = jnp.float32(bandit_jax.DEFAULT_HYPERS[policy])
     ref_fn = jax.jit(bandit_jax.make_sampled_round_fn(
-        policy, s_round, use_kernel=False))
+        policy, s_round, use_kernel=False, **kw))
     ker_fn = jax.jit(bandit_jax.make_sampled_round_fn(
-        policy, s_round, use_kernel=True, interpret=True))
+        policy, s_round, use_kernel=True, interpret=True, **kw))
     sr = sk = bandit_jax.BanditState.create(k)
+    sels, flags = [], []
     for r in range(rounds):
         args = (cand[r], pol_keys[r], time_keys[r], theta_mu, gamma_mu,
                 n_samp, eta, bits, hyper)
-        sr, sel_r, rt_r = ref_fn(sr, *args)
-        sk, sel_k, rt_k = ker_fn(sk, *args)
+        sr, sel_r, rt_r, *fl_r = ref_fn(sr, *args)
+        sk, sel_k, rt_k, *fl_k = ker_fn(sk, *args)
         np.testing.assert_array_equal(np.asarray(sel_r), np.asarray(sel_k))
         assert float(rt_r) == float(rt_k)
+        for a, b in zip(fl_r, fl_k, strict=True):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        sels.append(np.asarray(sel_k))
+        flags += [int(f) for fl in fl_k for f in np.asarray(fl)]
     for f in dataclasses.fields(sr):
         np.testing.assert_array_equal(
             np.asarray(getattr(sr, f.name)), np.asarray(getattr(sk, f.name)),
             err_msg=f"sampled kernel state.{f.name} != ref ({policy})")
+    if case == "faults":
+        assert {bandit_jax.FLAG_OK, bandit_jax.FLAG_DEADLINE} <= set(flags)
+    if case == "exhausted":
+        assert (np.stack(sels) == -1).any()
+
+
+@pytest.mark.parametrize("policy", bandit_jax.POLICY_NAMES)
+def test_sampled_kernel_route_spreads_times_only_when_read(policy):
+    """The kernel route spreads the [C] Eq. 8 draw into zeroed f32[K]
+    buffers (scatter_cand_times) only for a policy whose selection reads
+    the times; every other policy draws at its S selected slots, so no
+    scatter into a fresh f32[K] buffer is left in its program."""
+    import re
+
+    k, n_cand = 4096, 400
+    fn = bandit_jax.make_sampled_round_fn(policy, 5, use_kernel=True,
+                                          interpret=True)
+    f32 = jax.ShapeDtypeStruct((k,), jnp.float32)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+    text = jax.jit(fn).lower(
+        bandit_jax.BanditState.create(k),
+        jax.ShapeDtypeStruct((n_cand,), jnp.int32), key, key, f32, f32, f32,
+        scalar, scalar, scalar).as_text(dialect="hlo")
+    opcode = dict(re.findall(r"^\s*(?:ROOT )?([\w.\-]+) = \S+ ([\w\-]+)\(",
+                             text, re.M))
+    fresh = [name for name, operand in re.findall(
+        rf"^\s*(?:ROOT )?([\w.\-]+) = f32\[{k}\]\S* scatter\(([\w.\-]+),",
+        text, re.M) if opcode.get(operand) == "broadcast"]
+    reads = policy in bandit_jax.POLICY_READS_TIMES
+    assert len(fresh) == (2 if reads else 0), (policy, fresh)
 
 
 def test_fl_fast_chunked_and_unfused_bitwise():
