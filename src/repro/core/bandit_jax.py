@@ -1194,6 +1194,20 @@ def make_segmented_round_fn(policy: str, s_round: int, *, axis_name: str,
     return round_fn
 
 
+def segmented_merge_bytes(policy: str, n_req: int, s_round: int) -> int:
+    """Operand bytes one shard feeds the merge's collectives in one round
+    of :func:`make_segmented_round_fn`: the masked ``psum`` of the three
+    [C] means Eq. (8) reads (throughput, capability, dataset size), then
+    for a "greedy" policy one [C] ``psum`` per statistic it scores from
+    (``POLICY_STATS``), for a "score" policy the ``all_gather`` of its
+    local top-S values and slots.  Every exchanged value is 32-bit."""
+    if policy_kind(policy) == "greedy":
+        words = (3 + len(POLICY_STATS[policy])) * n_req
+    else:
+        words = 3 * n_req + 2 * s_round
+    return 4 * words
+
+
 # ---------------------------------------------------------------------------
 # Hierarchical two-level selection (the paper's mobile-network cell
 # structure): score CELLS from aggregated per-cell bandit stats, then poll

@@ -1122,8 +1122,9 @@ def sweep(scenario: Scenario | str = "paper-baseline",
         Cells selected per round under ``hierarchy="cells"``; default
         ~sqrt(n_cells), clamped to [1, n_cells].
 
-    When ``shard="clients"`` lands on a mesh that divides K (and the fast
-    fused path is on), the sweep runs the explicitly-sharded segmented
+    The client-sharded segmented route needs the mesh to divide K.  When
+    ``shard="clients"`` lands on such a mesh (and the fast fused path is
+    on), the sweep runs the explicitly-sharded segmented
     round loop: bandit state lives as contiguous [K/P] blocks inside one
     ``shard_map``, selection is per-shard local-top-S + an all-gather of
     P*S entries + a final Algorithm-1/argmax reduce on the compacted
@@ -1138,7 +1139,12 @@ def sweep(scenario: Scenario | str = "paper-baseline",
     one jit call: dispatch, and the compile on a cache miss) and
     ``sweep.fetch`` (the copy of the results to the host, which waits for
     the device, with argument ``bytes``).  ``bytes`` is the summed
-    ``nbytes`` of the arrays moved.  On the device side every op of the
+    ``nbytes`` of the arrays moved.  On the client-sharded segmented route
+    ``sweep.run`` also carries ``merge_bytes``: the operand bytes each
+    shard feeds the merge's collectives (the [C] ``psum`` and the top-S
+    ``all_gather``, core.bandit_jax.segmented_merge_bytes), summed over
+    every round, grid point and policy of the call; other routes carry no
+    such counter.  On the device side every op of the
     round loop carries one of four name scopes in its op_name
     (``core.bandit_jax.ROUND_SCOPES``): ``round.cand_draw`` (the Resource
     Request's candidate draw), ``round.times`` (Eq. 8 times, scenario
@@ -1226,7 +1232,12 @@ def sweep(scenario: Scenario | str = "paper-baseline",
                 jnp.asarray(g_seed))
         span.set_metadata(bytes=_nbytes(args))
 
-    with jax.profiler.TraceAnnotation("sweep.run"), \
+    # the segmented route's counter: operand bytes of its merge per shard
+    # over the whole call (every round of every grid point and policy)
+    run_meta = {"merge_bytes": n_grid * n_rounds * sum(
+        bandit_jax.segmented_merge_bytes(p, n_req, s_round)
+        for p in pol_names)} if use_segmented else {}
+    with jax.profiler.TraceAnnotation("sweep.run", **run_meta), \
             suppress_unusable_donation_warnings():
         # eta and seed (args[3:]) are donated: nothing reads them after
         out = _run_grid(

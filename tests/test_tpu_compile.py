@@ -179,7 +179,17 @@ def _maybe_vmap(one_chip, fn, args, vmapped):
 def test_topk_slots_kernel_compiles(one_chip, vmapped):
     # the segmented round's per-shard top-S runs over the [C] candidate
     # slice: C = K/10 = 10^5 at the four-chip K = 10^6 layout
-    c = 100_000
+    _topk_slots_compiles(one_chip, 100_000, vmapped)
+
+
+@pytest.mark.parametrize("vmapped", [False, True])
+def test_topk_slots_kernel_compiles_at_reddit_c(one_chip, vmapped):
+    # C = 166,082 at reddit-1.66e6-seg4's K = 1,660,820: not a multiple
+    # of the 1,024-element tile, so the kernel pads it
+    _topk_slots_compiles(one_chip, 166_082, vmapped)
+
+
+def _topk_slots_compiles(one_chip, c, vmapped):
     _maybe_vmap(one_chip,
                 lambda s, v: topk_slots_pallas(s, v, S_ROUND,
                                                interpret=False),
